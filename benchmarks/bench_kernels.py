@@ -94,7 +94,7 @@ def run(quick: bool = False) -> None:
             (kv_k, kv_v, x, idx, kv_rows, kv_rows, h_rows)),
     }
 
-    mode = "mosaic" if jax.default_backend() == "tpu" else "interpret"
+    mode = "interpret" if ops.default_interpret() else "mosaic"
     results: Dict[str, Dict] = {
         "_meta": {"backend": jax.default_backend(), "pallas_mode": mode,
                   "quick": quick, "shapes": s}}

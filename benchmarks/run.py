@@ -30,6 +30,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.smoke:
         args.quick = True
+    from repro.core.runtime import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (bench_kernels, bench_serving, fig2_drift,
                             fig4_latency, fig5_anisotropy, roofline,

@@ -18,9 +18,12 @@ Three small facilities that every layer above core can share:
     every retrace exactly (a Python wrapper around the function handed
     to ``jax.jit`` only executes at trace time, so its invocation count
     IS the trace count — and it is a no-op on traced values, so decode
-    outputs are byte-identical with counting on).  Where available,
-    ``jax.monitoring`` duration events add backend-compile wall time;
-    when the module is absent the trace counters still work alone.
+    outputs are byte-identical with counting on).  ``jax.monitoring``
+    duration events add backend-compile wall time.
+
+Beside them, :func:`enable_compile_cache` places JAX's persistent
+compilation cache for the entry points (serve launcher, benchmarks,
+chip smoke).
 
 Counting is passive and always-on: it is host-side, fires only at trace
 time (never per step), and costs one dict increment per compile — so
@@ -30,14 +33,33 @@ no enable flag.
 from __future__ import annotations
 
 import functools
+import os
 import threading
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 __all__ = [
     "track_executables", "live_executable_count", "drop_executables",
-    "CompileTracker", "compile_tracker",
+    "CompileTracker", "compile_tracker", "enable_compile_cache",
 ]
+
+# <repo>/.jax_cache: a fixed path (the path is part of every cache key,
+# so a directory that moves between runs never hits); git-ignored.
+REPO_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and nothing else is set here; otherwise the cache lives at
+    :data:`REPO_CACHE_DIR`.  Call before the first compile."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = REPO_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 _LOCK = threading.Lock()
 _TRACKED: "weakref.WeakSet" = weakref.WeakSet()
@@ -86,8 +108,8 @@ class CompileTracker:
     ``wrap(fn, name=..., lane=...)`` returns a function whose body runs
     only when JAX traces it — wrap BEFORE ``jax.jit``.  Each execution
     increments the per-name and per-lane trace counters exactly once
-    per (re)trace.  A guarded ``jax.monitoring`` listener adds compile
-    wall-time totals when the runtime exposes duration events.
+    per (re)trace.  A ``jax.monitoring`` listener adds compile
+    wall-time totals.
     """
 
     # monitoring event -> short key in the seconds table
@@ -138,20 +160,15 @@ class CompileTracker:
 
     # ---- jax.monitoring compile durations ----------------------------
 
-    def install_monitoring(self) -> bool:
-        """Attach the compile-duration listener once.  Returns whether
-        the runtime supports it; safe to call repeatedly."""
+    def install_monitoring(self) -> None:
+        """Attach the compile-duration listener once; safe to call
+        repeatedly."""
+        from jax import monitoring
         with self._lock:
             if self._listener_installed:
-                return True
-            try:
-                from jax import monitoring
-                register = monitoring.register_event_duration_secs_listener
-            except Exception:
-                return False
+                return
             self._listener_installed = True
-        register(self._on_event)
-        return True
+        monitoring.register_event_duration_secs_listener(self._on_event)
 
     def _on_event(self, event: str, duration: float, **kw) -> None:
         key = self._EVENTS.get(event)

@@ -42,20 +42,10 @@ def batch_axes_ctx(axes: Optional[Tuple[str, ...]]):
 
 
 def _current_mesh():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not getattr(mesh, "empty", True):
-            return mesh
-    except Exception:  # pragma: no cover - older jax
-        pass
-    try:  # `with mesh:` context (physical mesh)
-        from jax._src import mesh as mesh_src
-        pm = mesh_src.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:  # pragma: no cover
-        pass
-    return None
+    """The mesh of the enclosing ``with mesh:`` block, or None."""
+    from jax._src import mesh as mesh_src
+    pm = mesh_src.thread_resources.env.physical_mesh
+    return None if pm.empty else pm
 
 
 def _resolve(dim, names):

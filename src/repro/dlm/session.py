@@ -45,7 +45,6 @@ Typical use::
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -139,13 +138,12 @@ class DecodeSession:
             f"{getattr(self.strategy, 'name', 'strategy')}"
             f"/{getattr(self.strategy.backend, 'name', 'backend')}")
         self._tracker = runtime.compile_tracker()
+        # Weights and proxies are jit ARGUMENTS of every executable built
+        # here, never closed over: a closed-over array is baked into the
+        # program as a constant (gigabytes at published widths).
         self._step_fn = runtime.track_executables(jax.jit(
-            self._tracker.wrap(
-                functools.partial(
-                    decoding.serve_step, params, cfg,
-                    settings=self.settings, spa_proxies=spa_proxies,
-                    strategy=self.strategy, scheduler=self.scheduler),
-                name="serve_step", lane=self.label)))
+            self._tracker.wrap(self._serve_step, name="serve_step",
+                               lane=self.label)))
         self._loop_fns: Dict[bool, Any] = {}   # run_compiled, by can_refresh
         self._partial_fns: Dict[int, Any] = {}  # prefill_partial, by s0
         # shared-prefix rows awaiting copy-on-write (DESIGN.md §6):
@@ -282,6 +280,12 @@ class DecodeSession:
             return jax.random.PRNGKey(int(rng))
         return jnp.asarray(rng)
 
+    def _serve_step(self, params, spa_proxies, state: DecodeState):
+        return decoding.serve_step(
+            params, self.cfg, state, settings=self.settings,
+            spa_proxies=spa_proxies, strategy=self.strategy,
+            scheduler=self.scheduler)
+
     def _build_cache(self, tokens, extras, kv_len=None):
         return self.strategy.refresh_cache(self.params, self.cfg, tokens,
                                            extras, self.spa_proxies,
@@ -297,10 +301,10 @@ class DecodeSession:
         the compile amortizes like the lane step does)."""
         fn = self._partial_fns.get(s0)
         if fn is None:
-            def run(inputs, kv_view, kv_len):
+            def run(params, spa_proxies, inputs, kv_view, kv_len):
                 return decoding.prefill_partial(
-                    self.params, self.cfg, inputs, kv_view, s0,
-                    kv_len=kv_len, spa_proxies=self.spa_proxies,
+                    params, self.cfg, inputs, kv_view, s0,
+                    kv_len=kv_len, spa_proxies=spa_proxies,
                     strategy=self.strategy)
             fn = runtime.track_executables(jax.jit(self._tracker.wrap(
                 run, name="prefill_partial", lane=self.label)))
@@ -352,7 +356,8 @@ class DecodeSession:
                     for kind, bufs in arenas.items()}
                 inputs = dict(sub_extras)
                 inputs["tokens"] = sub_tokens
-                fresh = self._partial_fn(s0)(inputs, kv_view, sub_kv)
+                fresh = self._partial_fn(s0)(self.params, self.spa_proxies,
+                                             inputs, kv_view, sub_kv)
             arenas = cache_lib.paged_from_dense(arenas, sub_wt, fresh,
                                                 self.strategy.backend)
         return arenas
@@ -578,7 +583,8 @@ class DecodeSession:
                 self.poison_cache_pages(pages)
             jax.block_until_ready(self.state)
             t1 = time.perf_counter()
-            self.state, info = self._step_fn(self.state)
+            self.state, info = self._step_fn(self.params, self.spa_proxies,
+                                             self.state)
             t2 = time.perf_counter()
             jax.block_until_ready(self.state)
             t3 = time.perf_counter()
@@ -592,7 +598,8 @@ class DecodeSession:
         if self._poison_pages:
             pages, self._poison_pages = self._poison_pages, None
             self.poison_cache_pages(pages)
-        self.state, info = self._step_fn(self.state)
+        self.state, info = self._step_fn(self.params, self.spa_proxies,
+                                         self.state)
         self.steps_taken += 1
         return info
 
@@ -662,7 +669,8 @@ class DecodeSession:
         prof = self.profiler
         t0 = time.perf_counter() if prof is not None else 0.0
         state, n_done, n_ref = self._loop_fns[can_refresh](
-            self.state, jnp.asarray(max_steps, jnp.int32))
+            self.params, self.spa_proxies, self.state,
+            jnp.asarray(max_steps, jnp.int32))
         self.state = state
         n_done = int(jax.device_get(n_done))
         n_ref = int(jax.device_get(n_ref))
@@ -685,25 +693,20 @@ class DecodeSession:
         step counter (``state.step`` == completed steps, so the rebuild
         lands before steps R, 2R, ... exactly like ``_maybe_refresh``).
         """
-        step_fn = functools.partial(
-            decoding.serve_step, self.params, self.cfg,
-            settings=self.settings, spa_proxies=self.spa_proxies,
-            strategy=self.strategy, scheduler=self.scheduler)
         interval = self.refresh_interval
-        params, cfg = self.params, self.cfg
-        strategy, proxies = self.strategy, self.spa_proxies
+        cfg, strategy = self.cfg, self.strategy
 
-        def rebuilt(state: DecodeState) -> DecodeState:
-            cache = strategy.refresh_cache(params, cfg, state.tokens,
-                                           state.extras, proxies,
-                                           kv_len=state.kv_len)
-            if isinstance(state.cache, PagedCache):
-                old = state.cache
-                cache = cache_lib.repage(old.arenas, old.page_table,
-                                         cache, strategy.backend)
-            return state._replace(cache=cache)
+        def loop(params, proxies, state0: DecodeState, max_steps: jax.Array):
+            def rebuilt(state: DecodeState) -> DecodeState:
+                cache = strategy.refresh_cache(params, cfg, state.tokens,
+                                               state.extras, proxies,
+                                               kv_len=state.kv_len)
+                if isinstance(state.cache, PagedCache):
+                    old = state.cache
+                    cache = cache_lib.repage(old.arenas, old.page_table,
+                                             cache, strategy.backend)
+                return state._replace(cache=cache)
 
-        def loop(state0: DecodeState, max_steps: jax.Array):
             def cond(carry):
                 state, n_done, _ = carry
                 return jnp.logical_and(n_done < max_steps,
@@ -716,7 +719,7 @@ class DecodeSession:
                                          state.step % interval == 0)
                     state = jax.lax.cond(do, rebuilt, lambda s: s, state)
                     n_ref = n_ref + do.astype(jnp.int32)
-                state, _ = step_fn(state)
+                state, _ = self._serve_step(params, proxies, state)
                 return state, n_done + 1, n_ref
 
             zero = jnp.zeros((), jnp.int32)

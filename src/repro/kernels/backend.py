@@ -14,10 +14,12 @@ not.
 
   ``XlaBackend``    — the current jnp ops (the oracle; default).
   ``PallasBackend`` — TPU kernels (``kernels/*``); interpret mode on
-                      CPU.  Decodes byte-identically to ``XlaBackend``
-                      for every registered strategy and scheduler
-                      (tests/test_backend_parity.py) because the
-                      kernels mirror the XLA numerics op-for-op.
+                      CPU, where it decodes byte-identically to
+                      ``XlaBackend`` for every registered strategy and
+                      scheduler (tests/test_backend_parity.py) because
+                      the kernels mirror the XLA numerics op-for-op.
+                      On a TPU the two reduce in different orders and
+                      are not promised identical tokens.
 
 Dispatch rules (DESIGN.md §4.5): top-k/stratified SELECTION always
 stays in XLA (tiny, latency-bound, and ``jax.lax.top_k`` is already
@@ -191,9 +193,9 @@ class XlaBackend(KernelBackend):
 class PallasBackend(KernelBackend):
     """The Pallas TPU kernel suite on the hot path.
 
-    ``interpret=None`` resolves per process: real Mosaic lowering on a
-    TPU backend, interpret mode elsewhere (CPU CI validates the exact
-    TPU program logic).  ``block_q``/``block_k`` mirror the XLA flash
+    ``interpret=None`` resolves per process (``ops.default_interpret``):
+    real Mosaic lowering on a TPU backend, interpret mode on CPU (CPU CI
+    validates the TPU program logic), an error on anything else.  ``block_q``/``block_k`` mirror the XLA flash
     defaults so the online-softmax block structure — and therefore the
     f32 accumulation order — is identical across backends.
     """
@@ -207,7 +209,8 @@ class PallasBackend(KernelBackend):
     def _interp(self) -> bool:
         if self.interpret is not None:
             return self.interpret
-        return jax.default_backend() != "tpu"
+        from repro.kernels.ops import default_interpret
+        return default_interpret()
 
     def identifier_scores(self, strategy, bp, proxy_mat, x, p_cached,
                           page_table=None):

@@ -19,9 +19,15 @@ applied after the QK dot, masking before the running-max update, f32
 state) so the backends decode byte-identically.
 
 Grid: (B, H, nq, nk_or_band) — the kv axis minor (sequential on TPU), so
-VMEM scratch carries the softmax state per (batch, head, q-block). VMEM
-per step: bq*hd (q) + 2*bk*hd (kv) + bq*bk (scores) + scratch — (512,
-512) blocks with hd<=256 stay under ~4 MB.
+VMEM scratch carries the softmax state per (batch, head, q-block).
+Layout: q, k, v and the output keep their [B, S, heads*hd] row layout
+(no transposes in XLA); a block is one head's (rows, hd) column slab, so
+hd must be a multiple of 128 on a TPU.  Query positions arrive as a
+[B, kq, 1] column and int8 dequant scales as their natural [B, N, KVH]
+rows (the head's column is selected in VMEM); the per-row ``kv_len``
+and the banded kv starts are scalar-prefetched.  VMEM per step: bq*hd
+(q) + 2*bk*hd (kv) + bq*bk (scores) + scratch, double-buffered — (512,
+512) blocks with hd = 128 stay under ~4 MB.
 """
 from __future__ import annotations
 
@@ -35,15 +41,24 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _head_scale(s_ref, kv_head):
+    """[bk, 1] f32 dequant scale of one kv head from a [bk, KVH] block
+    (an exact masked select: a dynamic lane slice does not lower)."""
+    blk = s_ref[0].astype(jnp.float32)
+    sel = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1) == kv_head
+    return jnp.max(jnp.where(sel, blk, -jnp.inf), axis=1, keepdims=True)
+
+
 def _attn_step(qpos, q, k, v, ks, vs, o_ref, m_scr, l_scr, acc_scr, *,
                kv_base, j, nj, window: int, soft_cap: float,
-               n_valid: int, scale: float, kv_limit=None):
+               n_valid: int, scale: float, kv_limit):
     """One kv-block online-softmax update (shared by both grid flavors).
 
     ``kv_limit`` (scalar int32) is the batch row's valid canvas length
     (paged serving): kv positions >= kv_limit mask out exactly like the
     global ``n_valid`` pad bound, mirroring the XLA path's per-row
-    ``kv_len`` mask op-for-op."""
+    ``kv_len`` mask op-for-op.  ``qpos`` is [bq, 1]; ``ks``/``vs`` are
+    [bk, 1] scales or None."""
 
     @pl.when(j == 0)
     def _init():
@@ -52,8 +67,11 @@ def _attn_step(qpos, q, k, v, ks, vs, o_ref, m_scr, l_scr, acc_scr, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     qf = q.astype(jnp.float32)                        # [bq, hd]
-    kf = k.astype(jnp.float32) * ks[:, None].astype(jnp.float32)
-    vf = v.astype(jnp.float32) * vs[:, None].astype(jnp.float32)
+    kf = k.astype(jnp.float32)
+    vf = v.astype(jnp.float32)
+    if ks is not None:
+        kf = kf * ks
+        vf = vf * vs
 
     s = jax.lax.dot_general(qf, kf, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -61,21 +79,18 @@ def _attn_step(qpos, q, k, v, ks, vs, o_ref, m_scr, l_scr, acc_scr, *,
         s = soft_cap * jnp.tanh(s / soft_cap)
 
     kv_pos = kv_base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = kv_pos < n_valid
-    if kv_limit is not None:
-        valid = jnp.logical_and(valid, kv_pos < kv_limit)
+    valid = jnp.logical_and(kv_pos < n_valid, kv_pos < kv_limit)
     if window > 0:
-        valid = jnp.logical_and(valid,
-                                jnp.abs(qpos[:, None] - kv_pos) <= window)
+        valid = jnp.logical_and(valid, jnp.abs(qpos - kv_pos) <= window)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_scr[...]                               # [bq, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     p = jnp.where(valid, p, 0.0)
     alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - m_new))
-    l_new = alpha * l_scr[...] + jnp.sum(p, axis=-1)
-    acc = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+    l_new = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc_scr[...] * alpha + jax.lax.dot_general(
         p, vf, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
@@ -86,30 +101,29 @@ def _attn_step(qpos, q, k, v, ks, vs, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(j == nj - 1)
     def _finalize():
         l_safe = jnp.where(l_scr[...] == 0.0, 1.0, l_scr[...])
-        o_ref[0, 0] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
-def _dense_kernel(qpos_ref, kvl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, m_scr, l_scr, acc_scr, *, nk: int, bk: int,
-                  window: int, soft_cap: float, n_valid: int, scale: float):
-    j = pl.program_id(3)
-    _attn_step(qpos_ref[0], q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
-               ks_ref[0, 0], vs_ref[0, 0], o_ref, m_scr, l_scr, acc_scr,
-               kv_base=j * bk, j=j, nj=nk, window=window,
+def _kernel(*refs, banded: bool, scaled: bool, nj: int, bk: int, g: int,
+            window: int, soft_cap: float, n_valid: int, scale: float):
+    if banded:
+        starts_ref, kvl_ref, *refs = refs
+    else:
+        kvl_ref, *refs = refs
+    if scaled:
+        qpos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, *scr = refs
+    else:
+        qpos_ref, q_ref, k_ref, v_ref, o_ref, *scr = refs
+    bb, hh, i, j = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
+                    pl.program_id(3))
+    kvb = starts_ref[i] + j if banded else j
+    ks = vs = None
+    if scaled:
+        ks, vs = _head_scale(ks_ref, hh // g), _head_scale(vs_ref, hh // g)
+    _attn_step(qpos_ref[0], q_ref[0], k_ref[0], v_ref[0], ks, vs, o_ref,
+               *scr, kv_base=kvb * bk, j=j, nj=nj, window=window,
                soft_cap=soft_cap, n_valid=n_valid, scale=scale,
-               kv_limit=kvl_ref[0])
-
-
-def _banded_kernel(starts_ref, qpos_ref, kvl_ref, q_ref, k_ref, v_ref,
-                   ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                   n_band: int, bk: int, window: int, soft_cap: float,
-                   n_valid: int, scale: float):
-    i, j = pl.program_id(2), pl.program_id(3)
-    _attn_step(qpos_ref[0], q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
-               ks_ref[0, 0], vs_ref[0, 0], o_ref, m_scr, l_scr, acc_scr,
-               kv_base=(starts_ref[i] + j) * bk, j=j, nj=n_band,
-               window=window, soft_cap=soft_cap, n_valid=n_valid,
-               scale=scale, kv_limit=kvl_ref[0])
+               kv_limit=kvl_ref[bb])
 
 
 def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -135,6 +149,7 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     assert h % kvh == 0
     g = h // kvh
     scale = 1.0 / (hd ** 0.5)
+    scaled = k_scale is not None
 
     bq = min(block_q, kq)
     bk = min(block_k, n)
@@ -147,100 +162,73 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-    if k_scale is None:
-        k_scale = jnp.ones((b, k.shape[1], kvh), jnp.float32)
-        v_scale = jnp.ones((b, k.shape[1], kvh), jnp.float32)
-    elif pad_k:
-        k_scale = jnp.pad(k_scale, ((0, 0), (0, pad_k), (0, 0)))
-        v_scale = jnp.pad(v_scale, ((0, 0), (0, pad_k), (0, 0)))
-
-    qt = jnp.swapaxes(q, 1, 2)                      # [B, H, kq_p, hd]
-    kt = jnp.swapaxes(k, 1, 2)                      # [B, KVH, N_p, hd]
-    vt = jnp.swapaxes(v, 1, 2)
-    kst = jnp.swapaxes(k_scale, 1, 2).astype(jnp.float32)  # [B, KVH, N_p]
-    vst = jnp.swapaxes(v_scale, 1, 2).astype(jnp.float32)
+        if scaled:
+            k_scale = jnp.pad(k_scale, ((0, 0), (0, pad_k), (0, 0)))
+            v_scale = jnp.pad(v_scale, ((0, 0), (0, pad_k), (0, 0)))
+    kq_p, skv_p = q.shape[1], k.shape[1]
+    nq, nk = kq_p // bq, skv_p // bk
     q_pos = q_pos.astype(jnp.int32)
     kv_len = (jnp.full((b,), n, jnp.int32) if kv_len is None
               else kv_len.astype(jnp.int32))
 
-    kq_p, skv_p = qt.shape[2], kt.shape[2]
-    nq = kq_p // bq
-    nk = skv_p // bk
+    # [B, S, heads, hd] -> [B, S, heads*hd]: free reshapes, one head per
+    # (rows, hd) block
+    operands = [q_pos[..., None], q.reshape(b, kq_p, h * hd),
+                k.reshape(b, skv_p, kvh * hd), v.reshape(b, skv_p, kvh * hd)]
+    if scaled:
+        operands += [k_scale.astype(jnp.float32),
+                     v_scale.astype(jnp.float32)]
 
-    out_shape = jax.ShapeDtypeStruct((b, h, kq_p, hd), q.dtype)
-    scratch = [
-        pltpu.VMEM((bq,), jnp.float32),
-        pltpu.VMEM((bq,), jnp.float32),
-        pltpu.VMEM((bq, hd), jnp.float32),
-    ]
     use_band = (banded and window > 0 and q_span > 0
                 and n > (q_span + 2 * window + 2 * bk))
-
     if use_band:
         from repro.models.attention import band_width, banded_starts
-        n_band = band_width(q_span, window, bk, nk)
-        starts = banded_starts(q_pos.reshape(b, nq, bq), window, skv_p,
-                               n_band, bk)
+        nj = band_width(q_span, window, bk, nk)
+        prefetch = [banded_starts(q_pos.reshape(b, nq, bq), window, skv_p,
+                                  nj, bk), kv_len]
 
-        def kvi(bb, hh, i, j, st):
-            return (bb, hh // g, st[i] + j)
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h, nq, n_band),
-            in_specs=[
-                pl.BlockSpec((1, bq), lambda bb, hh, i, j, st: (bb, i)),
-                pl.BlockSpec((1,), lambda bb, hh, i, j, st: (bb,),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, bq, hd),
-                             lambda bb, hh, i, j, st: (bb, hh, i, 0)),
-                pl.BlockSpec((1, 1, bk, hd),
-                             lambda bb, hh, i, j, st: kvi(bb, hh, i, j, st)
-                             + (0,)),
-                pl.BlockSpec((1, 1, bk, hd),
-                             lambda bb, hh, i, j, st: kvi(bb, hh, i, j, st)
-                             + (0,)),
-                pl.BlockSpec((1, 1, bk), kvi),
-                pl.BlockSpec((1, 1, bk), kvi),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, bq, hd), lambda bb, hh, i, j, st: (bb, hh, i, 0)),
-            scratch_shapes=scratch,
-        )
-        out = pl.pallas_call(
-            functools.partial(_banded_kernel, n_band=n_band, bk=bk,
-                              window=window, soft_cap=soft_cap, n_valid=n,
-                              scale=scale),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(starts, q_pos, kv_len, qt, kt, vt, kst, vst)
+        def kv_block(i, j, st):
+            return st[i] + j
     else:
-        out = pl.pallas_call(
-            functools.partial(_dense_kernel, nk=nk, bk=bk, window=window,
-                              soft_cap=soft_cap, n_valid=n, scale=scale),
-            grid=(b, h, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, bq), lambda bb, hh, i, j: (bb, i)),
-                pl.BlockSpec((1,), lambda bb, hh, i, j: (bb,),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, bq, hd),
-                             lambda bb, hh, i, j: (bb, hh, i, 0)),
-                pl.BlockSpec((1, 1, bk, hd),
-                             lambda bb, hh, i, j: (bb, hh // g, j, 0)),
-                pl.BlockSpec((1, 1, bk, hd),
-                             lambda bb, hh, i, j: (bb, hh // g, j, 0)),
-                pl.BlockSpec((1, 1, bk),
-                             lambda bb, hh, i, j: (bb, hh // g, j)),
-                pl.BlockSpec((1, 1, bk),
-                             lambda bb, hh, i, j: (bb, hh // g, j)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, bq, hd), lambda bb, hh, i, j: (bb, hh, i, 0)),
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(q_pos, kv_len, qt, kt, vt, kst, vst)
+        nj = nk
+        prefetch = [kv_len]
 
-    out = jnp.swapaxes(out, 1, 2)[:, :kq]           # [B, kq, H, hd]
+        def kv_block(i, j, st):
+            return j
+
+    def q_map(bb, hh, i, j, *pre):
+        return (bb, i, hh)
+
+    def kv_map(bb, hh, i, j, *pre):
+        return (bb, kv_block(i, j, pre[0]), hh // g)
+
+    def scale_map(bb, hh, i, j, *pre):
+        return (bb, kv_block(i, j, pre[0]), 0)
+
+    in_specs = [
+        pl.BlockSpec((1, bq, 1), lambda bb, hh, i, j, *pre: (bb, i, 0)),
+        pl.BlockSpec((1, bq, hd), q_map),
+        pl.BlockSpec((1, bk, hd), kv_map),
+        pl.BlockSpec((1, bk, hd), kv_map),
+    ]
+    if scaled:
+        in_specs += [pl.BlockSpec((1, bk, kvh), scale_map)] * 2
+    out = pl.pallas_call(
+        functools.partial(_kernel, banded=use_band, scaled=scaled, nj=nj,
+                          bk=bk, g=g, window=window, soft_cap=soft_cap,
+                          n_valid=n, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, h, nq, nj),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, bq, hd), q_map),
+            scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, hd), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kq_p, h * hd), q.dtype),
+        interpret=interpret,
+    )(*prefetch, *operands)
+
+    out = out.reshape(b, kq_p, h, hd)[:, :kq]        # [B, kq, H, hd]
     return out[0] if unbatched else out

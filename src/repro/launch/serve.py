@@ -14,6 +14,13 @@ streams per-token ndjson events per request, with SLO-aware admission
 (``--slo-ttft`` / ``--slo-deadline``, seconds; 0 disables the policy).
 ``--client HOST:PORT`` instead runs a demo streaming client against a
 running server (see also ``examples/serve_stream.py``).
+
+Without ``--full-size`` the model is the reduced same-family variant
+(CPU-sized); with it, the architecture's published widths (a TPU run):
+
+  PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
+      --full-size --pool-pages 257 --page-size 16 --canvas 1024 \
+      --max-batch 4 --requests 8 --gen-len 64
 """
 from __future__ import annotations
 
@@ -25,6 +32,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch, reduced
+from repro.core import runtime
 from repro.core.strategy import REGISTRY, strategy_from_spec
 from repro.dlm.decoding import DecodeSettings
 from repro.models import transformer
@@ -36,6 +44,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llada-8b")
     ap.add_argument("--ckpt", default="")
+    ap.add_argument("--full-size", action="store_true",
+                    help="serve the architecture at its published widths "
+                         "(real hardware only); default: the reduced "
+                         "CPU-sized variant")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--canvas", type=int, default=64)
@@ -117,19 +129,24 @@ def main(argv=None):
     ap.add_argument("--jax-trace-dir", default="",
                     help="with --profile: also wrap the run in "
                          "jax.profiler.trace writing to this directory "
-                         "(when the runtime supports it)")
+                         "(a trace that cannot start raises)")
     args = ap.parse_args(argv)
 
     if args.client:
         return _run_client(args)
+    runtime.enable_compile_cache()
 
-    cfg = reduced(get_arch(args.arch))
+    cfg = get_arch(args.arch)
+    if not args.full_size:
+        cfg = reduced(cfg)
     if args.ckpt:
         params, meta = checkpoint.load_checkpoint(args.ckpt)
         print(f"loaded checkpoint {args.ckpt} ({meta})")
     else:
         params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-        print("no checkpoint given; serving an untrained reduced model")
+        print(f"no checkpoint given; serving an untrained "
+              f"{'full-size' if args.full_size else 'reduced'} "
+              f"{cfg.name} (random weights, seed 0)")
 
     if cfg.is_encoder_only:
         print(f"{cfg.name} is encoder-only; no decode serving path")
@@ -273,8 +290,6 @@ def _print_profile(engine) -> None:
     """``--profile`` report: step-time decomposition + the top-3
     most-retraced lane signatures (DESIGN.md §12).  Renders cleanly
     when zero steps were profiled (e.g. zero requests completed)."""
-    from repro.core import runtime
-
     print("step-time decomposition " + "-" * 39)
     print(engine.profiler.format_summary())
     top = runtime.compile_tracker().top_retraced(3)

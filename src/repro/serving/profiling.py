@@ -160,17 +160,14 @@ class StepProfiler:
     @contextlib.contextmanager
     def jax_trace(self):
         """Wrap a run in ``jax.profiler.trace`` when a trace dir was
-        requested and the runtime supports it; no-op otherwise."""
+        requested; no-op otherwise.  A trace that fails to start or stop
+        raises: a run asked to be traced does not pass silently
+        untraced."""
         if not self.jax_trace_dir:
             yield
             return
-        try:
-            import jax.profiler
-            cm = jax.profiler.trace(self.jax_trace_dir)
-        except Exception:
-            yield
-            return
-        with cm:
+        import jax.profiler
+        with jax.profiler.trace(self.jax_trace_dir):
             yield
 
     # ---- summaries ---------------------------------------------------

@@ -10,10 +10,16 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
+from hypothesis import settings  # noqa: E402
 
 from repro.configs import get_arch, reduced  # noqa: E402
 from repro.core import runtime  # noqa: E402
 from repro.models import transformer  # noqa: E402
+
+# Property tests draw the same examples on every run and keep no example
+# database on disk.
+settings.register_profile("repro", derandomize=True, database=None)
+settings.load_profile("repro")
 
 
 @pytest.fixture(autouse=True, scope="module")

@@ -3,7 +3,7 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import SPAConfig
 from repro.core import budget

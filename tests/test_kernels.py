@@ -106,11 +106,20 @@ def test_proxy_score_batched_grid():
     pc = jax.random.normal(ks[2], (3, 65, 32), jnp.bfloat16)
     s, p = proxy_score(x, w, pc, interpret=True, block_n=16)
     assert s.shape == (3, 65) and p.shape == (3, 65, 32)
+    xf, wf = np.asarray(x, np.float32), np.asarray(w, np.float32)
     for i in range(3):
         s_r, p_r = ref.proxy_score_ref(x[i], w, pc[i])
         np.testing.assert_allclose(s[i], s_r, rtol=4e-2, atol=4e-2)
-        np.testing.assert_array_equal(np.asarray(p[i], np.float32),
-                                      np.asarray(p_r, np.float32))
+        # The kernel projects 16-row blocks, the oracle all 65 rows in
+        # one matmul: XLA reduces the d-length f32 dot products in a
+        # different order, and the bf16 rounding of p can then land one
+        # ulp apart.  Bound: d * 2^-24 * sum|x||w| (f32 reassociation)
+        # plus one bf16 ulp (2^-7 relative).
+        p_k = np.asarray(p[i], np.float32)
+        p_o = np.asarray(p_r, np.float32)
+        bound = (2.0 ** -7 * np.abs(p_o)
+                 + 96 * 2.0 ** -24 * (np.abs(xf[i]) @ np.abs(wf)))
+        assert np.all(np.abs(p_k - p_o) <= bound)
 
 
 def test_cosine_drift_matches_cosine_similarity():
@@ -214,14 +223,15 @@ def test_banded_partial_q_block_matches_oracle():
 
 def test_scatter_update_multi_buffers():
     """K/V/H/proxy-style multi-buffer commit in one aliased call: mixed
-    dtypes/widths, sorted contiguous runs, and sentinel (>= N) drops."""
+    dtypes/widths, sorted runs sharing a tile slab, and sentinel (>= N)
+    drops."""
     rng = np.random.default_rng(3)
     ks = jax.random.split(jax.random.PRNGKey(10), 3)
     b, n, kk = 2, 64, 16
     c_f = jax.random.normal(ks[0], (b, n, 2, 8), jnp.bfloat16)
     c_i = jnp.asarray(rng.integers(-100, 100, (b, n, 12)), jnp.int8)
     c_s = jax.random.normal(ks[1], (b, n), jnp.float16)
-    # sorted with a contiguous run (batched-DMA path) + sentinel pads
+    # sorted with a contiguous run (one slab load/store) + sentinel pads
     idx = jnp.asarray(np.sort(np.stack([
         np.r_[rng.choice(40, 10, replace=False), 50, 51, 52, 53, n, n],
         np.r_[rng.choice(n, 14, replace=False), n, n]]), axis=-1),
@@ -240,9 +250,9 @@ def test_scatter_update_multi_buffers():
 
 
 def test_scatter_update_unsorted_endpoint_collision():
-    """Regression: an unsorted run-sized chunk whose endpoints differ by
-    exactly run-1 (e.g. [5,20,7,9,2,3,4,12]) must NOT take the batched
-    contiguous-DMA store — every element has to sit at first + t."""
+    """Regression: an unsorted chunk whose endpoints differ by exactly
+    its length-1 (e.g. [5,20,7,9,2,3,4,12]) leaves and re-enters tile
+    slabs — every row has to land at its own index, not at first + t."""
     cache = jnp.zeros((1, 32, 8))
     idx = jnp.asarray([[5, 20, 7, 9, 2, 3, 4, 12]], jnp.int32)
     rows = jax.random.normal(jax.random.PRNGKey(12), (1, 8, 8))

@@ -123,11 +123,18 @@ def test_proxy_score_paged_matches_dense(paged_fixture):
     dense = XLA_BACKEND.gather_pages(parena[None], pt)[0]
     s_p, p_p = ps.proxy_score_paged(x, w, parena, pt, interpret=True)
     s_d, p_d = ps.proxy_score(x, w, dense, interpret=True)
-    np.testing.assert_array_equal(np.asarray(s_p), np.asarray(s_d))
+    # The paged and dense kernels are different programs: XLA fuses the
+    # cosine's r-length f32 sums differently around the page DMAs, so a
+    # score may move by a few f32 ulp (|cos| <= 1, r = 8 terms:
+    # reassociation error <= r * 2^-24 * 2 < 1e-6).  The projection has
+    # the same block shape in both and stays exact.
+    np.testing.assert_allclose(np.asarray(s_p), np.asarray(s_d),
+                               rtol=0, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(p_p), np.asarray(p_d))
     c_p = ps.cosine_drift_paged(p_p, parena, pt, interpret=True)
     c_d = ps.cosine_drift(p_p, dense, interpret=True)
-    np.testing.assert_array_equal(np.asarray(c_p), np.asarray(c_d))
+    np.testing.assert_allclose(np.asarray(c_p), np.asarray(c_d),
+                               rtol=0, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -10,19 +10,12 @@ from repro.configs import ARCHS, ASSIGNED, SHAPES, get_arch, supports_shape
 from repro.distributed import sharding as shd
 
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)              # jax >= 0.5
-    except TypeError:                                  # jax 0.4.x
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 def mesh_single():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh_multi():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class FakeLeaf:
