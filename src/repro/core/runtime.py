@@ -19,7 +19,8 @@ Three small facilities that every layer above core can share:
     to ``jax.jit`` only executes at trace time, so its invocation count
     IS the trace count — and it is a no-op on traced values, so decode
     outputs are byte-identical with counting on).  ``jax.monitoring``
-    duration events add backend-compile wall time.
+    duration events add backend-compile wall time; its persistent
+    compile-cache hit events tell true compiles from cache reads.
 
 Beside them, :func:`enable_compile_cache` places JAX's persistent
 compilation cache for the entry points (serve launcher, benchmarks,
@@ -110,6 +111,12 @@ class CompileTracker:
     increments the per-name and per-lane trace counters exactly once
     per (re)trace.  A ``jax.monitoring`` listener adds compile
     wall-time totals.
+
+    ``event_counts["backend_compile"]`` counts backend compile
+    REQUESTS: JAX reports one whether the persistent compilation cache
+    serves the program or XLA compiles it.  ``event_counts
+    ["cache_hits"]`` counts the ones the cache served, and the
+    snapshot's ``compiles`` the difference — the programs XLA compiled.
     """
 
     # monitoring event -> short key in the seconds table
@@ -118,6 +125,8 @@ class CompileTracker:
         "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowering",
         "/jax/core/compile/jaxpr_trace_duration": "tracing",
     }
+    # monitoring count event -> key in event_counts
+    _COUNTS = {"/jax/compilation_cache/cache_hits": "cache_hits"}
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -169,6 +178,7 @@ class CompileTracker:
                 return
             self._listener_installed = True
         monitoring.register_event_duration_secs_listener(self._on_event)
+        monitoring.register_event_listener(self._on_count)
 
     def _on_event(self, event: str, duration: float, **kw) -> None:
         key = self._EVENTS.get(event)
@@ -179,17 +189,27 @@ class CompileTracker:
             self.event_seconds[key] = \
                 self.event_seconds.get(key, 0.0) + float(duration)
 
+    def _on_count(self, event: str, **kw) -> None:
+        key = self._COUNTS.get(event)
+        if key is None:
+            return
+        with self._lock:
+            self.event_counts[key] = self.event_counts.get(key, 0) + 1
+
     # ---- exposition --------------------------------------------------
 
     def snapshot(self) -> Dict[str, Any]:
         """JSON-safe dump (bench metrics artifact embeds this)."""
         with self._lock:
+            hits = self.event_counts.get("cache_hits", 0)
+            compiles = self.event_counts.get("backend_compile", 0) - hits
             return {
                 "traces": dict(self.traces),
                 "lane_traces": dict(self.lane_traces),
                 "event_counts": dict(self.event_counts),
                 "event_seconds": {k: round(v, 6) for k, v in
                                   self.event_seconds.items()},
+                "compiles": compiles,
                 "live_executables": live_executable_count(),
             }
 
@@ -205,11 +225,16 @@ class CompileTracker:
                 "spa_runtime_trace_total",
                 "function (re)traces by jitted entry point",
                 labels={"fn": name}).set(n)
+        hits = events.pop("cache_hits", 0)
         for key, n in sorted(events.items()):
             registry.counter(
                 "spa_runtime_compile_events_total",
                 "jax.monitoring compile events by stage",
                 labels={"stage": key}).set(n)
+        registry.counter(
+            "spa_runtime_compile_cache_hits_total",
+            "compile requests the persistent compilation cache served",
+        ).set(hits)
         for key, s in sorted(seconds.items()):
             registry.counter(
                 "spa_runtime_compile_seconds_total",

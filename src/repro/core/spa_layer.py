@@ -165,11 +165,9 @@ def spa_attn_block(cfg: ModelConfig, kind: str, bp: Params,
     w = layer_window(cfg, kind)
 
     if strategy.full_attn_ident:
-        x = common.rms_norm(h, bp["norm1"], cfg.norm_eps)
-        h_out, cache_sl, aux, idx = _attn_out_identifier_block(
-            cfg, kind, bp, cache_sl, h, x, k_upd, policy, strategy,
+        return _attn_out_identifier_block(
+            cfg, kind, bp, cache_sl, h, k_upd, policy, strategy,
             page_table=page_table, kv_len=kv_len)
-        return h_out, cache_sl, aux, idx
 
     # ---- Phase 1: identification & selection ----
     # Cosine drift is invariant to per-row scale, so the rms division of
@@ -178,55 +176,57 @@ def spa_attn_block(cfg: ModelConfig, kind: str, bp: Params,
     # only the k SELECTED rows afterwards. This keeps the full-sequence
     # tensor in bf16 (the gather's cross-shard all-reduce halves) and
     # skips an N*d norm per layer.
-    ident_in = h * (1.0 + bp["norm1"]).astype(h.dtype)
-    scores, p_now, proxy_now = _identifier_scores(
-        strategy, bp, proxy_mat, ident_in, cache_sl, scores_override,
-        prev_idx, page_table=page_table)
-    scores = _mask_tail_scores(scores, n, kv_len)
-    nb = stratify_blocks_for(n, k_upd) if w > 0 else 0
-    if nb > 1:
-        idx = selection.select_stratified(scores, k_upd, nb)
-        span = q_span_bound(n, k_upd, nb)
-    else:
-        idx = selection.select_topk_drift(scores, k_upd)
-        span = 0
+    with jax.named_scope("spa_identify"):
+        ident_in = h * (1.0 + bp["norm1"]).astype(h.dtype)
+        scores, p_now, proxy_now = _identifier_scores(
+            strategy, bp, proxy_mat, ident_in, cache_sl, scores_override,
+            prev_idx, page_table=page_table)
+        scores = _mask_tail_scores(scores, n, kv_len)
+        nb = stratify_blocks_for(n, k_upd) if w > 0 else 0
+        if nb > 1:
+            idx = selection.select_stratified(scores, k_upd, nb)
+            span = q_span_bound(n, k_upd, nb)
+        else:
+            idx = selection.select_topk_drift(scores, k_upd)
+            span = 0
     k_eff = idx.shape[1]
 
+    # ---- Phase 2: attention with partially cached KV ----
     # NOTE §Perf: sharding the selected rows over "model" here was
     # MEASURED WORSE (7x compute): GSPMD lowers a cross-shard gather with
     # sharded output to a one-hot matmul (B*k*N*d FLOPs). Rows stay
     # replicated over "model"; the gather costs one all-reduce per layer.
     # The backend's gather_norm emits BOTH the raw rows (residual) and
     # the rms-normed rows (QKV input) in one pass over the k rows.
-    h_rows, x_rows = strategy.backend.gather_norm(h, idx, bp["norm1"],
-                                                  cfg.norm_eps)
-
-    # ---- Phase 2: attention with partially cached KV ----
-    q, k_new, v_new = qkv_project(bp, x_rows, cfg, idx)
-    cache_sl = strategy.commit_kv(cache_sl, idx, k_new, v_new, policy)
-    kf, vf, ks, vs = cache_lib.read_kv_for_attention(cache_sl, policy)
-    attn = strategy.backend.attention(
-        q, kf, vf, k_scale=ks, v_scale=vs, q_positions=idx, window=w,
-        soft_cap=cfg.attn_softcap, banded=(w > 0 and span > 0),
-        q_span=span, kv_len=kv_len)
-    from repro.distributed.hints import shard_hint
-    attn_out = shard_hint(attn.reshape(b, k_eff, cfg.q_dim) @ bp["wo"],
-                          "batch", "keep", None)
-    if cfg.post_norms:
-        attn_out = common.rms_norm(attn_out, bp["norm_post_attn"],
-                                   cfg.norm_eps)
-    h_mid = h_rows + attn_out
+    with jax.named_scope("spa_attend"):
+        h_rows, x_rows = strategy.backend.gather_norm(h, idx, bp["norm1"],
+                                                      cfg.norm_eps)
+        q, k_new, v_new = qkv_project(bp, x_rows, cfg, idx)
+        cache_sl = strategy.commit_kv(cache_sl, idx, k_new, v_new, policy)
+        kf, vf, ks, vs = cache_lib.read_kv_for_attention(cache_sl, policy)
+        attn = strategy.backend.attention(
+            q, kf, vf, k_scale=ks, v_scale=vs, q_positions=idx, window=w,
+            soft_cap=cfg.attn_softcap, banded=(w > 0 and span > 0),
+            q_span=span, kv_len=kv_len)
+        from repro.distributed.hints import shard_hint
+        attn_out = shard_hint(attn.reshape(b, k_eff, cfg.q_dim) @ bp["wo"],
+                              "batch", "keep", None)
+        if cfg.post_norms:
+            attn_out = common.rms_norm(attn_out, bp["norm_post_attn"],
+                                       cfg.norm_eps)
+        h_mid = h_rows + attn_out
 
     # ---- Phase 3: FFN & output update ----
-    y = common.rms_norm(h_mid, bp["norm2"], cfg.norm_eps)
-    ffn_out, aux = apply_ffn_or_moe(bp, y, cfg)
-    if cfg.post_norms:
-        ffn_out = common.rms_norm(ffn_out, bp["norm_post_ffn"],
-                                  cfg.norm_eps)
-    y_rows = h_mid + ffn_out
-    cache_sl = strategy.commit(cache_sl, idx, y_rows, policy,
-                               p_now=p_now, proxy_now=proxy_now,
-                               page_table=page_table)
+    with jax.named_scope("spa_ffn"):
+        y = common.rms_norm(h_mid, bp["norm2"], cfg.norm_eps)
+        ffn_out, aux = apply_ffn_or_moe(bp, y, cfg)
+        if cfg.post_norms:
+            ffn_out = common.rms_norm(ffn_out, bp["norm_post_ffn"],
+                                      cfg.norm_eps)
+        y_rows = h_mid + ffn_out
+        cache_sl = strategy.commit(cache_sl, idx, y_rows, policy,
+                                   p_now=p_now, proxy_now=proxy_now,
+                                   page_table=page_table)
 
     cache_sl = _hint_cache_slice(
         cache_sl, b, skip=(("proxy",) if page_table is not None else ()))
@@ -240,44 +240,47 @@ def spa_attn_block(cfg: ModelConfig, kind: str, bp: Params,
     return h_out, cache_sl, aux, idx
 
 
-def _attn_out_identifier_block(cfg, kind, bp, cache_sl, h, x, k_upd,
-                               policy, strategy, page_table=None,
-                               kv_len=None):
+def _attn_out_identifier_block(cfg, kind, bp, cache_sl, h, k_upd, policy,
+                               strategy, page_table=None, kv_len=None):
     """Table-1 'attn output' identifier: full attention is computed for ALL
     rows against the (stale) cached KV purely for identification; only the
     FFN runs sparsely. Matches the paper's cost profile (slower than the
     value proxy, still much faster than vanilla)."""
     b, n, d = h.shape
     w = layer_window(cfg, kind)
-    positions = jnp.broadcast_to(jnp.arange(n)[None], (b, n))
-    q_all, k_all, v_all = qkv_project(bp, x, cfg, positions)
-    kf, vf, ks, vs = cache_lib.read_kv_for_attention(cache_sl, policy)
-    attn_all = strategy.backend.attention(
-        q_all, kf, vf, k_scale=ks, v_scale=vs, window=w,
-        soft_cap=cfg.attn_softcap, banded=(w > 0), kv_len=kv_len)
-    attn_all = attn_all.reshape(b, n, cfg.q_dim) @ bp["wo"]
-    if cfg.post_norms:
-        attn_all = common.rms_norm(attn_all, bp["norm_post_attn"],
-                                   cfg.norm_eps)
-    scores = strategy.backend.score_drift(strategy, attn_all,
-                                          cache_sl["proxy"],
-                                          page_table=page_table)
-    scores = _mask_tail_scores(scores, n, kv_len)
-    idx = selection.select_topk_drift(scores, k_upd)
+    with jax.named_scope("spa_identify"):
+        x = common.rms_norm(h, bp["norm1"], cfg.norm_eps)
+        positions = jnp.broadcast_to(jnp.arange(n)[None], (b, n))
+        q_all, k_all, v_all = qkv_project(bp, x, cfg, positions)
+        kf, vf, ks, vs = cache_lib.read_kv_for_attention(cache_sl, policy)
+        attn_all = strategy.backend.attention(
+            q_all, kf, vf, k_scale=ks, v_scale=vs, window=w,
+            soft_cap=cfg.attn_softcap, banded=(w > 0), kv_len=kv_len)
+        attn_all = attn_all.reshape(b, n, cfg.q_dim) @ bp["wo"]
+        if cfg.post_norms:
+            attn_all = common.rms_norm(attn_all, bp["norm_post_attn"],
+                                       cfg.norm_eps)
+        scores = strategy.backend.score_drift(strategy, attn_all,
+                                              cache_sl["proxy"],
+                                              page_table=page_table)
+        scores = _mask_tail_scores(scores, n, kv_len)
+        idx = selection.select_topk_drift(scores, k_upd)
 
-    cache_sl = strategy.commit_kv(
-        cache_sl, idx, selection.gather_rows(k_all, idx),
-        selection.gather_rows(v_all, idx), policy)
-    h_mid = selection.gather_rows(h, idx) + selection.gather_rows(
-        attn_all, idx)
-    y = common.rms_norm(h_mid, bp["norm2"], cfg.norm_eps)
-    ffn_out, aux = apply_ffn_or_moe(bp, y, cfg)
-    if cfg.post_norms:
-        ffn_out = common.rms_norm(ffn_out, bp["norm_post_ffn"],
-                                  cfg.norm_eps)
-    y_rows = h_mid + ffn_out
-    cache_sl = strategy.commit(cache_sl, idx, y_rows, policy,
-                               attn_all=attn_all, page_table=page_table)
+    with jax.named_scope("spa_attend"):
+        cache_sl = strategy.commit_kv(
+            cache_sl, idx, selection.gather_rows(k_all, idx),
+            selection.gather_rows(v_all, idx), policy)
+        h_mid = selection.gather_rows(h, idx) + selection.gather_rows(
+            attn_all, idx)
+    with jax.named_scope("spa_ffn"):
+        y = common.rms_norm(h_mid, bp["norm2"], cfg.norm_eps)
+        ffn_out, aux = apply_ffn_or_moe(bp, y, cfg)
+        if cfg.post_norms:
+            ffn_out = common.rms_norm(ffn_out, bp["norm_post_ffn"],
+                                      cfg.norm_eps)
+        y_rows = h_mid + ffn_out
+        cache_sl = strategy.commit(cache_sl, idx, y_rows, policy,
+                                   attn_all=attn_all, page_table=page_table)
     cache_sl = _hint_cache_slice(
         cache_sl, b, skip=(("proxy",) if page_table is not None else ()))
     h_out = cache_lib.read_h_full(cache_sl, policy, h.dtype)

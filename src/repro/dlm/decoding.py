@@ -299,8 +299,9 @@ def serve_step(params: Params, cfg: ModelConfig, state: DecodeState,
     # at the end — all through strategy.backend, so XLA stays the
     # byte-identical oracle for the Pallas paged kernels.
     paged = isinstance(cache, cache_lib.PagedCache)
-    view = (cache_lib.paged_step_view(cache, backend=strategy.backend)
-            if paged else cache)
+    with jax.named_scope("cache_view"):
+        view = (cache_lib.paged_step_view(cache, backend=strategy.backend)
+                if paged else cache)
     page_table = cache.page_table if paged else None
 
     if not strategy.uses_cache or not view:
@@ -313,59 +314,62 @@ def serve_step(params: Params, cfg: ModelConfig, state: DecodeState,
             scores_override=scores_override,
             changed_idx=state.committed, strategy=strategy,
             page_table=page_table, kv_len=state.kv_len)
-        new_cache = (cache_lib.paged_step_commit(
-            cache, new_view, backend=strategy.backend)
-            if paged else new_view)
+        with jax.named_scope("cache_commit"):
+            new_cache = (cache_lib.paged_step_commit(
+                cache, new_view, backend=strategy.backend)
+                if paged else new_view)
 
     # Candidate-limited logit evaluation + commit.
-    cand_idx, is_masked = _candidate_positions(
-        tokens, mask_id, settings.n_candidates, state.active)
-    h_cand = selection.gather_rows(h, cand_idx + offset)
-    logits = transformer.logits_from_hidden(params, cfg, h_cand)
-    # the model must never commit the [MASK] token itself
-    logits = logits.at[..., mask_id].set(-jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    conf = jnp.max(probs, axis=-1)                   # [B, n_cand]
-    pred = jnp.argmax(probs, axis=-1).astype(tokens.dtype)
+    with jax.named_scope("logits"):
+        cand_idx, is_masked = _candidate_positions(
+            tokens, mask_id, settings.n_candidates, state.active)
+        h_cand = selection.gather_rows(h, cand_idx + offset)
+        logits = transformer.logits_from_hidden(params, cfg, h_cand)
+        # the model must never commit the [MASK] token itself
+        logits = logits.at[..., mask_id].set(-jnp.inf)
+        probs = jax.nn.softmax(logits, axis=-1)
+        conf = jnp.max(probs, axis=-1)                   # [B, n_cand]
+        pred = jnp.argmax(probs, axis=-1).astype(tokens.dtype)
 
-    cand_is_masked = selection.gather_rows(
-        is_masked[..., None], cand_idx)[..., 0]
-    conf = jnp.where(cand_is_masked, conf, -jnp.inf)
+        cand_is_masked = selection.gather_rows(
+            is_masked[..., None], cand_idx)[..., 0]
+        conf = jnp.where(cand_is_masked, conf, -jnp.inf)
 
     # Commit decision is the scheduler's (dlm/scheduler.py).  The rng
     # chain lives in DecodeState so stochastic schedules replay exactly
     # in both the host loop and run_compiled's while_loop.
-    rng_next, step_rng = state.rng, None
-    if scheduler.uses_rng:
-        assert state.rng is not None, \
-            f"scheduler {scheduler.name!r} needs an rng: pass rng= to " \
-            "DecodeSession.prefill()/attach()"
-        rng_next, step_rng = jax.random.split(state.rng)
-    active = state.active if state.active is not None \
-        else jnp.ones_like(tokens, bool)
-    view = CommitView(
-        logits=logits, conf=conf, pred=pred, cand_idx=cand_idx,
-        cand_open=cand_is_masked, open_mask=is_masked, active=active,
-        rng=step_rng)
-    commit, pred = scheduler.select_commits(view)
-    commit = jnp.logical_and(commit, cand_is_masked)
+    with jax.named_scope("unmask"):
+        rng_next, step_rng = state.rng, None
+        if scheduler.uses_rng:
+            assert state.rng is not None, \
+                f"scheduler {scheduler.name!r} needs an rng: pass rng= to " \
+                "DecodeSession.prefill()/attach()"
+            rng_next, step_rng = jax.random.split(state.rng)
+        active = state.active if state.active is not None \
+            else jnp.ones_like(tokens, bool)
+        view = CommitView(
+            logits=logits, conf=conf, pred=pred, cand_idx=cand_idx,
+            cand_open=cand_is_masked, open_mask=is_masked, active=active,
+            rng=step_rng)
+        commit, pred = scheduler.select_commits(view)
+        commit = jnp.logical_and(commit, cand_is_masked)
 
-    new_vals = jnp.where(commit, pred, selection.gather_rows(
-        tokens[..., None], cand_idx)[..., 0])
-    new_tokens = selection.scatter_rows(
-        tokens[..., None], cand_idx, new_vals[..., None])[..., 0]
+        new_vals = jnp.where(commit, pred, selection.gather_rows(
+            tokens[..., None], cand_idx)[..., 0])
+        new_tokens = selection.scatter_rows(
+            tokens[..., None], cand_idx, new_vals[..., None])[..., 0]
 
-    committed_pos = jnp.where(commit, cand_idx, -1)
-    ring = settings.commit_ring
-    _, order = jax.lax.top_k(committed_pos.astype(jnp.float32),
-                             min(ring, committed_pos.shape[-1]))
-    committed = jnp.take_along_axis(committed_pos, order, axis=-1)
-    if committed.shape[-1] < ring:
-        committed = jnp.pad(committed, ((0, 0),
-                                        (0, ring - committed.shape[-1])),
-                            constant_values=-1)
+        committed_pos = jnp.where(commit, cand_idx, -1)
+        ring = settings.commit_ring
+        _, order = jax.lax.top_k(committed_pos.astype(jnp.float32),
+                                 min(ring, committed_pos.shape[-1]))
+        committed = jnp.take_along_axis(committed_pos, order, axis=-1)
+        if committed.shape[-1] < ring:
+            committed = jnp.pad(committed, ((0, 0),
+                                            (0, ring - committed.shape[-1])),
+                                constant_values=-1)
 
-    n_committed = jnp.sum(commit, axis=-1)
+        n_committed = jnp.sum(commit, axis=-1)
     new_state = DecodeState(
         tokens=new_tokens, cache=new_cache, step=state.step + 1,
         committed=committed,

@@ -141,6 +141,7 @@ def proxy_score(x: jax.Array, proxy_mat: jax.Array, p_cached: jax.Array,
             jax.ShapeDtypeStruct((b, n_p, r), x.dtype),
         ],
         interpret=interpret,
+        name="proxy_score",
     )(x, proxy_mat, p_cached)
     scores, p_now = scores[:, :n, 0], p_now[:, :n]
     return (scores[0], p_now[0]) if unbatched else (scores, p_now)
@@ -167,6 +168,7 @@ def cosine_drift(x: jax.Array, p_cached: jax.Array, *, eps: float = 1e-8,
         out_specs=pl.BlockSpec((1, bn, 1), lambda bb, i: (bb, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_p, 1), jnp.float32),
         interpret=interpret,
+        name="cosine_drift",
     )(x, p_cached)
     scores = scores[:, :n, 0]
     return scores[0] if unbatched else scores
@@ -256,6 +258,7 @@ def proxy_score_paged(x: jax.Array, proxy_mat: jax.Array,
             jax.ShapeDtypeStruct((b, n, r), x.dtype),
         ],
         interpret=interpret,
+        name="proxy_score_paged",
     )(pt.astype(jnp.int32).reshape(-1), x, proxy_mat, arena)
     return scores[..., 0], p_now
 
@@ -302,6 +305,7 @@ def cosine_drift_paged(x: jax.Array, arena: jax.Array, pt: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, n, 1), jnp.float32),
         interpret=interpret,
+        name="cosine_drift_paged",
     )(pt.astype(jnp.int32).reshape(-1), x, arena)
     return scores[..., 0]
 
@@ -397,5 +401,6 @@ def gather_norm(h: jax.Array, idx: jax.Array, weight: jax.Array,
             jax.ShapeDtypeStruct((b, kp, d), h.dtype),
         ],
         interpret=interpret,
+        name="gather_norm",
     )(idx.reshape(-1), weight.astype(jnp.float32).reshape(1, d), h)
     return rows[:, :k], normed[:, :k]

@@ -167,9 +167,10 @@ def _scatter_kernel(*refs, paged: bool, n_bufs: int, bk: int, kp: int,
 
 
 def _scatter_call(bufs, idx, rows, pt, *, rows_per_lead: int,
-                  block_k: int, interpret: bool):
+                  block_k: int, interpret: bool, name: str):
     """One aliased pallas_call committing rows [B, k, *feat] into every
-    buffer of ``bufs`` ([lead, rows_per_lead, *feat] each)."""
+    buffer of ``bufs`` ([lead, rows_per_lead, *feat] each); ``name`` is
+    the kernel's name in the compiled program (its public caller's)."""
     b, k = idx.shape
     n_log = 0 if pt is None else pt.shape[1]
     n = rows_per_lead if pt is None else n_log * rows_per_lead
@@ -220,6 +221,7 @@ def _scatter_call(bufs, idx, rows, pt, *, rows_per_lead: int,
         out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in bufs],
         input_output_aliases={n_pre + m + j: j for j in range(m)},
         interpret=interpret,
+        name=name,
     )(*prefetch, *payloads, *bufs)
     return tuple(outs)
 
@@ -241,7 +243,8 @@ def scatter_update_multi(caches: Sequence[jax.Array], idx: jax.Array,
     outs = _scatter_call([_rows3(c) for c in caches], idx,
                          [_rows3(r) for r in rows], None,
                          rows_per_lead=caches[0].shape[1],
-                         block_k=block_k, interpret=interpret)
+                         block_k=block_k, interpret=interpret,
+                         name="scatter_update_multi")
     return tuple(o.reshape(s) for o, s in zip(outs, shapes))
 
 
@@ -256,7 +259,7 @@ def scatter_rows_paged(arena: jax.Array, pt: jax.Array, idx: jax.Array,
     input->output)."""
     (out,) = _scatter_call([_rows3(arena)], idx, [_rows3(rows)], pt,
                            rows_per_lead=arena.shape[1], block_k=block_k,
-                           interpret=interpret)
+                           interpret=interpret, name="scatter_rows_paged")
     return out.reshape(arena.shape)
 
 
@@ -268,8 +271,9 @@ def scatter_update(cache: jax.Array, idx: jax.Array, rows: jax.Array,
     Single-buffer unbatched form of ``scatter_update_multi`` (the cache
     buffer is aliased input->output — in-place on TPU when the caller's
     buffer is donatable)."""
-    (out,) = scatter_update_multi([cache[None]], idx[None], [rows[None]],
-                                  block_k=block_k, interpret=interpret)
+    (out,) = _scatter_call([cache[None]], idx[None], [rows[None]], None,
+                           rows_per_lead=cache.shape[0], block_k=block_k,
+                           interpret=interpret, name="scatter_update")
     return out[0]
 
 
@@ -338,6 +342,7 @@ def gather_pages(arena: jax.Array, pt: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((l, b, n_log * page) + shape[3:],
                                        arena.dtype),
         interpret=interpret,
+        name="gather_pages",
     )(pt.astype(jnp.int32).reshape(-1), arena)
 
 
@@ -372,4 +377,5 @@ def scatter_pages(arena: jax.Array, pt: jax.Array, dense: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct(shape, arena.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="scatter_pages",
     )(pt.astype(jnp.int32).reshape(-1), dense.astype(arena.dtype), arena)

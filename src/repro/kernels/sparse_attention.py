@@ -228,6 +228,7 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kq_p, h * hd), q.dtype),
         interpret=interpret,
+        name="sparse_attention",
     )(*prefetch, *operands)
 
     out = out.reshape(b, kq_p, h, hd)[:, :kq]        # [B, kq, H, hd]

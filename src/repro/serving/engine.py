@@ -500,15 +500,19 @@ class ServingEngine:
             self._tr.name_track(PID_ENGINE, lid, f"lane{lid}")
         return lid
 
-    def _phase_end(self, lid: int, name: str) -> None:
-        """Close an engine-phase span and fold its duration into the
-        step-time-breakdown histogram."""
-        tr = self._tr
-        tr.end(PID_ENGINE, lid, name)
+    def _phase(self, lid: int, name: str):
+        """One engine phase of lane ``lid``: ``engine/<name>`` in a
+        ``jax.profiler`` trace always; while tracing, also a span on
+        the lane's track whose duration feeds the step-time-breakdown
+        histogram."""
+        return self._tr.span(PID_ENGINE, lid, name, cat="phase",
+                             on_close=self._observe_phase)
+
+    def _observe_phase(self, ev) -> None:
         self.telemetry.registry.histogram(
             "spa_engine_phase_seconds",
             "per-iteration step-time breakdown",
-            labels={"phase": name}).observe(tr.events[-1].dur)
+            labels={"phase": ev.name}).observe(ev.dur)
 
     def _note_cache_dynamics(self, sess: DecodeSession,
                              strategy: CacheStrategy, n_live: int) -> None:
@@ -1773,27 +1777,20 @@ class ServingEngine:
                 continue
             if self.faults is not None and self.faults.fire("step_nan"):
                 self._inject_nan(slots, sess)
-            if tr.enabled:
-                tr.begin(PID_ENGINE, lid, "dispatch", cat="phase")
-            info = sess.step()
+            with self._phase(lid, "dispatch"):
+                info = sess.step()
             # double-buffered dispatch (DESIGN.md §8): the jitted step
             # is dispatched but NOT synced yet — mailbox intake, SLO
             # shedding and next-candidate prefix planning run on the
             # host while the device step is in flight.
-            if tr.enabled:
-                self._phase_end(lid, "dispatch")
-                tr.begin(PID_ENGINE, lid, "host_overlap", cat="phase")
-            self._host_overlap(lane, slots)
-            if tr.enabled:
-                self._phase_end(lid, "host_overlap")
+            with self._phase(lid, "host_overlap"):
+                self._host_overlap(lane, slots)
             self.stats.steps += 1
             if self.paged:
                 self.pool.note_step()
+            with self._phase(lid, "host_sync"):
+                n_comm = np.asarray(info["n_committed"])  # first host sync
             if tr.enabled:
-                tr.begin(PID_ENGINE, lid, "host_sync", cat="phase")
-            n_comm = np.asarray(info["n_committed"])  # first host sync
-            if tr.enabled:
-                self._phase_end(lid, "host_sync")
                 if self.paged:
                     tr.counter(PID_ENGINE, "pool_pages",
                                {"used": self.pool.used,
@@ -1828,8 +1825,9 @@ class ServingEngine:
                     s.first_token_at = now
                 s.last_commit_at = now
                 s.tokens_done += int(n_comm[i])
-            self._stream_tokens(slots, sess, p_lens)
-            n_masked = np.asarray(sess.state.n_masked)
+            with self._phase(lid, "stream"):
+                self._stream_tokens(slots, sess, p_lens)
+                n_masked = np.asarray(sess.state.n_masked)
             finished, dead = [], []
             for i, s in enumerate(slots):
                 if s is None:
@@ -1845,35 +1843,34 @@ class ServingEngine:
                     finished.append(i)
             progressed = bool(int(n_comm.sum()) > 0 or finished or dead)
             if sup is not None:
-                if tr.enabled:
-                    tr.begin(PID_ENGINE, lid, "supervisor", cat="phase")
-                fired = sup.watchdog(progressed)
-                if fired:
-                    self._watchdog_recover(lane, slots, sess)
-                else:
-                    sup.on_iteration()
-                if tr.enabled:
-                    self._phase_end(lid, "supervisor")
+                with self._phase(lid, "supervisor"):
+                    fired = sup.watchdog(progressed)
+                    if fired:
+                        self._watchdog_recover(lane, slots, sess)
+                    else:
+                        sup.on_iteration()
                 if fired:
                     continue
             if not (finished or dead) and not (self.continuous
                                                and self._admission_dirty):
                 continue
             if finished or dead:
-                toks = sess.host_tokens()
-                for i in finished:
-                    self._harvest(slots[i], toks[i], p_lens[i])
-                    slots[i] = None
-                for i in dead:
-                    req = slots[i]
-                    slots[i] = None
-                    self._finalize_aborted(req)
-                if self.paged:
-                    # zero the finished rows' page-table entries BEFORE
-                    # their freed pages can be re-allocated below — a
-                    # stale entry would let the dead row's next
-                    # write-back corrupt the new owner's pages
-                    sess.release_rows(finished + dead)
+                with self._phase(lid, "release"):
+                    toks = sess.host_tokens()
+                    for i in finished:
+                        self._harvest(slots[i], toks[i], p_lens[i])
+                        slots[i] = None
+                    for i in dead:
+                        req = slots[i]
+                        slots[i] = None
+                        self._finalize_aborted(req)
+                    if self.paged:
+                        # zero the finished rows' page-table entries
+                        # BEFORE their freed pages can be re-allocated
+                        # below — a stale entry would let the dead
+                        # row's next write-back corrupt the new owner's
+                        # pages
+                        sess.release_rows(finished + dead)
             if nan_rows:
                 # NaN quarantine (§10): the poisoned rows died above;
                 # force-preempt every surviving lane-mate so the batch
@@ -1884,55 +1881,56 @@ class ServingEngine:
                     if r is not None:
                         self._preempt(i, r, slots, sess)
                 continue
-            swap_rows, swap_tokens, swap_active = [], [], []
-            swap_kv, swap_pt, swap_com = [], [], []
-            swap_shared: List[SharedPrefix] = []
-            while self.continuous:
-                # fill every empty slot — and let _admit_one MAKE one by
-                # preempting a lower-priority row when a high-priority
-                # arrival finds the batch/pool full — until admission
-                # stalls or the queue drains
-                req = self._admit_one(lane, slots, sess,
-                                      protected=tuple(swap_rows))
-                if req is None:
-                    break
-                empty = [i for i, s in enumerate(slots) if s is None]
-                i = empty[0]
-                row, act, com, p_len = self._canvas_row(req)
-                slots[i] = req
-                p_lens[i] = p_len
-                ages[i] = req.served_steps
-                if req.started_at is None:
-                    req.started_at = self._now()
-                swap_rows.append(i)
-                swap_tokens.append(row)
-                swap_active.append(act)
-                swap_kv.append(req.row_len)
-                if self.paged and strategy.uses_cache:
-                    pt_row, spec = self._attach_spec(req, i)
-                    swap_pt.append(pt_row)
-                    if spec is not None:
-                        swap_shared.append(spec)
-                else:
-                    swap_pt.append([0] * n_log)
-                swap_com.append(com if com is not None else np.full(
-                    (committed0.shape[1],), -1, np.int32))
-            self._admission_dirty = False
-            if swap_rows:
-                if self.paged:
-                    sess.replace_rows(
-                        swap_rows, np.stack(swap_tokens),
-                        np.stack(swap_active),
-                        row_kv_len=np.asarray(swap_kv, np.int32),
-                        row_page_table=np.asarray(swap_pt, np.int32),
-                        row_committed=np.stack(swap_com),
-                        row_shared=swap_shared or None)
-                    for i in swap_rows:
-                        self._maybe_publish(slots[i], sess)
-                else:
-                    sess.replace_rows(swap_rows, np.stack(swap_tokens),
-                                      np.stack(swap_active))
-                self.stats.swaps += len(swap_rows)
+            with self._phase(lid, "admit"):
+                swap_rows, swap_tokens, swap_active = [], [], []
+                swap_kv, swap_pt, swap_com = [], [], []
+                swap_shared: List[SharedPrefix] = []
+                while self.continuous:
+                    # fill every empty slot — and let _admit_one MAKE one by
+                    # preempting a lower-priority row when a high-priority
+                    # arrival finds the batch/pool full — until admission
+                    # stalls or the queue drains
+                    req = self._admit_one(lane, slots, sess,
+                                          protected=tuple(swap_rows))
+                    if req is None:
+                        break
+                    empty = [i for i, s in enumerate(slots) if s is None]
+                    i = empty[0]
+                    row, act, com, p_len = self._canvas_row(req)
+                    slots[i] = req
+                    p_lens[i] = p_len
+                    ages[i] = req.served_steps
+                    if req.started_at is None:
+                        req.started_at = self._now()
+                    swap_rows.append(i)
+                    swap_tokens.append(row)
+                    swap_active.append(act)
+                    swap_kv.append(req.row_len)
+                    if self.paged and strategy.uses_cache:
+                        pt_row, spec = self._attach_spec(req, i)
+                        swap_pt.append(pt_row)
+                        if spec is not None:
+                            swap_shared.append(spec)
+                    else:
+                        swap_pt.append([0] * n_log)
+                    swap_com.append(com if com is not None else np.full(
+                        (committed0.shape[1],), -1, np.int32))
+                self._admission_dirty = False
+                if swap_rows:
+                    if self.paged:
+                        sess.replace_rows(
+                            swap_rows, np.stack(swap_tokens),
+                            np.stack(swap_active),
+                            row_kv_len=np.asarray(swap_kv, np.int32),
+                            row_page_table=np.asarray(swap_pt, np.int32),
+                            row_committed=np.stack(swap_com),
+                            row_shared=swap_shared or None)
+                        for i in swap_rows:
+                            self._maybe_publish(slots[i], sess)
+                    else:
+                        sess.replace_rows(swap_rows, np.stack(swap_tokens),
+                                          np.stack(swap_active))
+                    self.stats.swaps += len(swap_rows)
             parked = [i for i in finished + dead if i not in swap_rows
                       and slots[i] is None]
             if parked and not self.paged:   # paged rows released above

@@ -1,6 +1,6 @@
 """Compute-path profiling beneath the §11 telemetry facade (DESIGN.md §12).
 
-Three cooperating pieces, all OFF by default (construct nothing and the
+Two cooperating pieces, all OFF by default (construct nothing and the
 decode path is untouched):
 
   * :class:`StepProfiler` — device-time decomposition of the decode
@@ -16,14 +16,6 @@ decode path is untouched):
     derived).  Observations land in the §11 registry
     (``spa_profile_*``) and, when a tracer is live, as slices on a
     dedicated device track in the Perfetto export.
-  * :class:`KernelPhaseProbes` — per-phase attribution of the SPA
-    pipeline (identify → gather → attend → scatter → page gather).
-    The jitted serve step is one fused executable, so phases cannot be
-    fenced inside it without changing the program; the probes instead
-    REPLAY each phase through the session's own ``KernelBackend`` stage
-    at cfg/strategy-derived shapes, jitted standalone and timed with a
-    compile/steady split.  They never touch live session state —
-    byte-identity with profiling on is structural, not incidental.
   * :class:`ProfileStore` — persisted per-(kernel, shape, backend,
     block-config) timing records (``BENCH_artifacts/
     kernel_profiles.json``), written by ``benchmarks/bench_kernels.py``
@@ -45,8 +37,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 from repro.serving.telemetry import (PID_DEVICE, Telemetry, TraceEvent)
 
 __all__ = [
-    "time_compile_steady", "StepProfiler", "KernelPhaseProbes",
-    "ProfileStore", "default_profile_path",
+    "time_compile_steady", "StepProfiler", "ProfileStore",
+    "default_profile_path",
 ]
 
 
@@ -222,101 +214,6 @@ class StepProfiler:
         if not lines:
             return "  (no profiled steps recorded)"
         return "\n".join("  " + ln for ln in lines)
-
-
-class KernelPhaseProbes:
-    """Synthetic per-phase replay of the SPA pipeline through a
-    KernelBackend (identify → gather → attend → scatter → page_gather).
-
-    Shapes derive from (cfg, strategy): proxy rank, head layout and
-    d_model are the real ones; canvas length and selection width are
-    probe parameters.  Each probe is jitted standalone and timed with
-    the compile/steady split, recording
-    ``spa_profile_phase_seconds{phase=,backend=}`` histograms.
-    """
-
-    def __init__(self, cfg, *, strategy=None, backend=None,
-                 batch: int = 2, seq: int = 128,
-                 n_selected: Optional[int] = None, page: int = 16,
-                 registry=None):
-        from repro.core.strategy import resolve_strategy
-        from repro.kernels.backend import resolve_backend
-        self.cfg = cfg
-        self.strategy = resolve_strategy(cfg, strategy)
-        self.backend = (resolve_backend(backend) if backend is not None
-                        else self.strategy.backend)
-        self.batch = batch
-        self.seq = seq
-        self.n_selected = n_selected or max(8, seq // 4)
-        self.page = page
-        self.registry = registry
-
-    def _build(self) -> Dict[str, Tuple[Callable, tuple]]:
-        import jax
-        import jax.numpy as jnp
-        cfg, strat, bk = self.cfg, self.strategy, self.backend
-        b, n, k = self.batch, self.seq, self.n_selected
-        d, hh, kvh, hd = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                          cfg.head_dim)
-        keys = jax.random.split(jax.random.PRNGKey(0), 8)
-        x = jax.random.normal(keys[0], (b, n, d))
-        idx = jnp.sort(jax.random.randint(keys[1], (b, k), 0, n))
-        norm_w = jax.random.normal(keys[2], (d,)) * 0.1
-        q = jax.random.normal(keys[3], (b, k, hh, hd))
-        kk = jax.random.normal(keys[4], (b, n, kvh, hd))
-        vv = jax.random.normal(keys[5], (b, n, kvh, hd))
-        probes: Dict[str, Tuple[Callable, tuple]] = {}
-        r = strat.proxy_dim(cfg)
-        if r:
-            p_now = jax.random.normal(keys[6], (b, n, r))
-            p_cached = jax.random.normal(keys[7], (b, n, r))
-            probes["identify"] = (
-                jax.jit(lambda pn, pc: bk.score_drift(strat, pn, pc)),
-                (p_now, p_cached))
-        probes["gather"] = (
-            jax.jit(lambda h, i, w: bk.gather_norm(h, i, w,
-                                                   cfg.norm_eps)),
-            (x, idx, norm_w))
-        probes["attend"] = (
-            jax.jit(lambda a, c, e, i: bk.attention(a, c, e,
-                                                    q_positions=i)),
-            (q, kk, vv, idx))
-        rows_k = jax.random.normal(keys[6], (b, k, kvh, hd))
-        rows_h = jax.random.normal(keys[7], (b, k, d))
-        probes["scatter"] = (
-            jax.jit(lambda bk_, bv_, bh_, i, rk, rv, rh: bk.scatter_multi(
-                {"k": bk_, "v": bv_, "h": bh_}, i,
-                {"k": rk, "v": rv, "h": rh})),
-            (kk, vv, x, idx, rows_k, rows_k, rows_h))
-        n_log = max(n // self.page, 1)
-        n_pages = b * n_log + 1
-        arena = jax.random.normal(keys[0], (1, n_pages, self.page, hd))
-        ptab = jax.random.randint(keys[1], (b, n_log), 0, n_pages)
-        probes["page_gather"] = (
-            jax.jit(lambda a, pt: bk.gather_pages(a, pt)), (arena, ptab))
-        return probes
-
-    def run(self, reps: int = 3) -> Dict[str, Dict[str, float]]:
-        """Time every phase probe; returns (and records)
-        {phase: {compile_s, steady_s}}."""
-        out: Dict[str, Dict[str, float]] = {}
-        bname = getattr(self.backend, "name",
-                        type(self.backend).__name__)
-        for phase, (fn, args) in self._build().items():
-            compile_s, steady_s = time_compile_steady(fn, *args,
-                                                      reps=reps)
-            out[phase] = {"compile_s": compile_s, "steady_s": steady_s}
-            if self.registry is not None:
-                labels = {"phase": phase, "backend": bname}
-                self.registry.histogram(
-                    "spa_profile_phase_seconds",
-                    "synthetic per-phase replay (steady state)",
-                    labels=labels).observe(steady_s)
-                self.registry.histogram(
-                    "spa_profile_phase_compile_seconds",
-                    "synthetic per-phase replay (first call)",
-                    labels=labels).observe(compile_s)
-        return out
 
 
 def default_profile_path() -> str:

@@ -15,7 +15,11 @@ so a chaos replay and its trace can be diffed line-for-line:
     event JSON (``{"traceEvents": [...]}``) that loads directly in
     Perfetto / chrome://tracing.  Spans nest per track; the tracer
     refuses double-closes and can report orphans, which the tests
-    assert on.
+    assert on.  ``Tracer.span`` also names the same work on the
+    profiler's clock: a ``jax.profiler.TraceAnnotation`` called
+    ``<process>/<name>`` (``engine/dispatch``), entered whether or not
+    the Chrome tracer is on, so a ``jax.profiler`` trace shows what the
+    engine was doing beside the device's operations (DESIGN.md §12).
 
 Naming conventions (enforced by convention, documented in DESIGN.md §11):
 metric names are ``spa_<subsystem>_<quantity>[_<unit>]`` with
@@ -33,6 +37,8 @@ import json
 import math
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
@@ -372,6 +378,8 @@ PID_ENGINE = 1
 PID_REQUESTS = 2
 PID_EVENTS = 3
 PID_DEVICE = 4      # step/loop device-time slices (serving/profiling.py)
+PROCESS_NAMES = {PID_ENGINE: "engine", PID_REQUESTS: "requests",
+                 PID_EVENTS: "events", PID_DEVICE: "device"}
 
 
 @dataclasses.dataclass
@@ -416,6 +424,32 @@ class Span:
     closed: bool = False
 
 
+class _SpanScope:
+    """One :meth:`Tracer.span` while the tracer is enabled: the Chrome
+    span inside the profiler annotation."""
+
+    __slots__ = ("tracer", "ann", "pid", "tid", "name", "cat", "on_close")
+
+    def __init__(self, tracer: "Tracer", ann: TraceAnnotation, pid: int,
+                 tid: int, name: str, cat: str,
+                 on_close: Optional[Callable[[TraceEvent], None]]):
+        self.tracer, self.ann, self.pid, self.tid = tracer, ann, pid, tid
+        self.name, self.cat, self.on_close = name, cat, on_close
+
+    def __enter__(self) -> "_SpanScope":
+        self.ann.__enter__()
+        self.tracer.begin(self.pid, self.tid, self.name, cat=self.cat)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        try:
+            self.tracer.end(self.pid, self.tid, self.name)
+            if self.on_close is not None:
+                self.on_close(self.tracer.events[-1])
+        finally:
+            self.ann.__exit__(*exc)
+
+
 class Tracer:
     """Span tracer over (pid, tid) tracks with per-track nesting.
 
@@ -424,7 +458,8 @@ class Tracer:
     complete-event.  Ending an already-closed span raises — the
     continuity tests lean on that.  When disabled every call is a
     near-free early return, which is what keeps the telemetry-off
-    fast path at zero cost.
+    fast path at zero cost.  :meth:`span` is the one call for work
+    that should also show in a ``jax.profiler`` trace.
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
@@ -475,6 +510,19 @@ class Tracer:
             name=sp.name, ph="X", ts=sp.t0, dur=self._now() - sp.t0,
             pid=pid, tid=tid, cat=sp.cat, args=sp.args))
         return sp
+
+    def span(self, pid: int, tid: int, name: str, cat: str = "",
+             on_close: Optional[Callable[[TraceEvent], None]] = None):
+        """Context manager naming one piece of work in two sinks:
+        always a ``jax.profiler.TraceAnnotation`` ``<process>/<name>``
+        (``engine/host_sync`` for ``PID_ENGINE``; close to free with no
+        profiler session running), and, while the tracer is enabled,
+        the span ``begin``/``end`` record on the engine clock, handed
+        to ``on_close`` once complete."""
+        ann = TraceAnnotation(f"{PROCESS_NAMES[pid]}/{name}")
+        if not self.enabled:
+            return ann
+        return _SpanScope(self, ann, pid, tid, name, cat, on_close)
 
     def close_track(self, pid: int, tid: int,
                     args: Optional[Dict[str, Any]] = None) -> int:
@@ -530,10 +578,7 @@ class Tracer:
         for (pid, tid), name in sorted(self._track_names.items()):
             evs.append({"name": "thread_name", "ph": "M", "pid": pid,
                         "tid": tid, "args": {"name": name}})
-        for pid, pname in ((PID_ENGINE, "engine"),
-                           (PID_REQUESTS, "requests"),
-                           (PID_EVENTS, "events"),
-                           (PID_DEVICE, "device")):
+        for pid, pname in PROCESS_NAMES.items():
             evs.append({"name": "process_name", "ph": "M", "pid": pid,
                         "tid": 0, "args": {"name": pname}})
         evs.extend(e.to_chrome() for e in self.events)

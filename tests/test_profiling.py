@@ -32,8 +32,8 @@ from repro.core.strategy import NoCache, SPACache, ValueProxyCache
 from repro.dlm.session import DecodeSession
 from repro.kernels.backend import PallasBackend
 from repro.serving.engine import ServingEngine
-from repro.serving.profiling import (KernelPhaseProbes, ProfileStore,
-                                     StepProfiler, time_compile_steady)
+from repro.serving.profiling import (ProfileStore, StepProfiler,
+                                     time_compile_steady)
 from repro.serving.telemetry import Telemetry
 
 PAGE, CANVAS = 4, 16
@@ -179,6 +179,57 @@ def test_compile_tracker_counts_traces_exactly():
     assert snap["traces"] == {"f": 2}
 
 
+def test_compile_tracker_tells_cache_hits_from_compiles(tmp_path):
+    """A program the persistent compilation cache serves is a compile
+    request but no compile: ``backend_compile`` counts both, the new
+    ``cache_hits`` key the second, ``compiles`` the difference."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro.serving.telemetry import MetricsRegistry
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    tracker = runtime.compile_tracker()
+
+    def fresh():                     # same program, new jit cache entry
+        def cache_probe(x):
+            return jax.numpy.sin(x) * 3.0 + 1.0
+        return jax.jit(cache_probe)
+
+    x = np.ones((7,), np.float32)
+    jax.config.update(names[0], str(tmp_path))
+    jax.config.update(names[1], 0)
+    jax.config.update(names[2], 0)
+    cc.reset_cache()
+    try:
+        s0 = tracker.snapshot()
+        fresh()(x).block_until_ready()       # compiled, then cached
+        s1 = tracker.snapshot()
+        fresh()(x).block_until_ready()       # served by the cache
+        s2 = tracker.snapshot()
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
+
+    def delta(a, b, key):
+        return b["event_counts"].get(key, 0) - a["event_counts"].get(key, 0)
+
+    assert delta(s0, s1, "backend_compile") == 1
+    assert delta(s0, s1, "cache_hits") == 0
+    assert s1["compiles"] - s0["compiles"] == 1
+    assert delta(s1, s2, "backend_compile") == 1
+    assert delta(s1, s2, "cache_hits") == 1
+    assert s2["compiles"] == s1["compiles"]
+    reg = MetricsRegistry()
+    tracker.export_metrics(reg)
+    snap = reg.snapshot()
+    assert snap["spa_runtime_compile_cache_hits_total"] \
+        == tracker.snapshot()["event_counts"]["cache_hits"]
+    assert 'spa_runtime_compile_events_total{stage="cache_hits"}' \
+        not in snap
+
+
 def test_session_trace_counts_are_shape_stable(small):
     """A second identically shaped decode through the SAME session adds
     zero retraces; the bench_serving Part 6 budget gate relies on this
@@ -294,30 +345,6 @@ def test_debug_pool_json_mid_churn(small):
     assert set(mid["pool"]) >= {"capacity", "used", "fragmentation",
                                 "arena_bytes"}
     assert mid["host_pool"]["unit_budget"] > 0
-
-
-# ---------------------------------------------------------------------------
-# Kernel-phase probes
-# ---------------------------------------------------------------------------
-
-def test_kernel_phase_probes_smoke(small):
-    from repro.serving.telemetry import MetricsRegistry
-    cfg, _, _ = small
-    reg = MetricsRegistry()
-    probes = KernelPhaseProbes(cfg, strategy=STRATEGIES["spa"],
-                               batch=1, seq=32, n_selected=8, page=8,
-                               registry=reg)
-    out = probes.run(reps=1)
-    assert {"identify", "gather", "attend", "scatter",
-            "page_gather"} <= set(out)
-    for rec in out.values():
-        assert rec["compile_s"] > 0 and rec["steady_s"] > 0
-    snap = reg.snapshot()
-    assert any(k.startswith("spa_profile_phase_seconds") for k in snap)
-    # cache-less strategies have no proxy to score
-    out2 = KernelPhaseProbes(cfg, strategy=NoCache(), batch=1, seq=32,
-                             n_selected=8, page=8).run(reps=1)
-    assert "identify" not in out2
 
 
 def test_time_compile_steady_orders():

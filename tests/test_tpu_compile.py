@@ -10,6 +10,7 @@ process may load the TPU library, and the suite runs under several
 workers that all import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,10 +118,43 @@ KERNELS = {
 }
 
 
+# the ``name`` each kernel's pallas_call passes: its public function's
+KERNEL_NAMES = {k: k for k in KERNELS}
+KERNEL_NAMES.update({"sparse_attention_int8": "sparse_attention",
+                     "sparse_attention_banded": "sparse_attention",
+                     "gather_pages_h": "gather_pages"})
+
+
+@pytest.fixture(scope="module")
+def compiled_text(one_chip):
+    """The compiled v5e text of a kernel of ``KERNELS``, compiled once
+    for every test of this file."""
+    texts = {}
+
+    def text(name):
+        if name not in texts:
+            fn, specs = KERNELS[name]
+            args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                    for s, d in specs]
+            texts[name] = jax.jit(fn).lower(*args).compile().as_text()
+        return texts[name]
+    return text
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
-def test_kernel_compiles_for_v5e(one_chip, name):
-    fn, specs = KERNELS[name]
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for s, d in specs]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_kernel_compiles_for_v5e(compiled_text, name):
+    assert "tpu_custom_call" in compiled_text(name)
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_carries_its_name_on_v5e(compiled_text, name):
+    """The kernel's custom call is named after it, and its op path ends
+    in it, so a device trace finds it by name and not by its shapes."""
+    kname = KERNEL_NAMES[name]
+    calls = [ln for ln in compiled_text(name).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(rf"\s*(ROOT )?%{kname}(\.\d+)? = ", ln), ln[:200]
+        assert re.search(rf'op_name="[^"]*/{kname}/pallas_call"', ln), \
+            ln[:200]
