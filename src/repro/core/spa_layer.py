@@ -348,35 +348,35 @@ def spa_forward(params: Params, cfg: ModelConfig,
 
     if (_homogeneous_attention(cfg) and cfg.scan_layers
             and cfg.n_layers >= 8 and scores_override is None):
-        # The cache rides in the scan CARRY (updated with
-        # dynamic_update_slice per layer) rather than as xs/ys — while-loop
-        # carries update in place under XLA buffer donation, so the
-        # multi-GB cache stacks exist ONCE instead of as input + output +
-        # copy (3x) buffers.
+        # Each segment scans over absolute layer ids: the body reads layer
+        # ``l_idx`` of the full weight and proxy stacks and of the WHOLE
+        # cache stack it carries, and updates that layer in place
+        # (dynamic_update_slice).  A segment sliced out of a stack before
+        # the scan is materialised every step; a dynamic_slice inside the
+        # body fuses into the dot that reads it.  While-loop carries
+        # update in place under XLA buffer donation, so the multi-GB cache
+        # stacks exist ONCE.
         kind = cfg.layer_pattern[0]
-        segments = budget.bucketize(ks, strategy.n_buckets)
-        new_slices: List = []
-        for (a, b_end, kseg) in segments:
-            bp_sl = jax.tree.map(lambda t: t[a:b_end],
-                                 params["blocks"][kind])
-            cache_seg = jax.tree.map(lambda t: t[a:b_end], cache[kind])
-            prox = (spa_proxies[kind][a:b_end]
-                    if uses_proxy_mat and spa_proxies else None)
+        blocks = params["blocks"][kind]
+        prox_stack = (spa_proxies[kind]
+                      if uses_proxy_mat and spa_proxies else None)
+        cache_kind = cache[kind]
 
-            def body(carry, xs, _kseg=kseg):
+        def layer_of(t, l_idx):
+            return jax.lax.dynamic_index_in_dim(t, l_idx, 0, keepdims=False)
+
+        for (a, b_end, kseg) in budget.bucketize(ks, strategy.n_buckets):
+
+            def body(carry, l_idx, _kseg=kseg):
                 if incremental:
                     h_c, aux_c, cache_c, prev_c = carry
                 else:
                     h_c, aux_c, cache_c = carry
                     prev_c = None
-                if prox is not None:
-                    bp_l, l_idx, pm = xs
-                else:
-                    bp_l, l_idx = xs
-                    pm = None
-                csl = jax.tree.map(
-                    lambda t: jax.lax.dynamic_index_in_dim(
-                        t, l_idx, 0, keepdims=False), cache_c)
+                bp_l = jax.tree.map(lambda t: layer_of(t, l_idx), blocks)
+                pm = (layer_of(prox_stack, l_idx)
+                      if prox_stack is not None else None)
+                csl = jax.tree.map(lambda t: layer_of(t, l_idx), cache_c)
                 h_c, csl_new, aux, idx = spa_attn_block(
                     cfg, kind, bp_l, pm, csl, h_c, _kseg, policy,
                     strategy, prev_idx=prev_c, page_table=page_table,
@@ -390,21 +390,15 @@ def spa_forward(params: Params, cfg: ModelConfig,
                             pad_idx(idx)), None
                 return (h_c, aux_c + aux, cache_c), None
 
-            seg_len = b_end - a
-            layer_ids = jnp.arange(seg_len, dtype=jnp.int32)
-            xs = (bp_sl, layer_ids, prox) if prox is not None \
-                else (bp_sl, layer_ids)
-            init = (h, aux_total, cache_seg, prev) if incremental \
-                else (h, aux_total, cache_seg)
-            carry, _ = jax.lax.scan(body, init, xs)
+            layer_ids = jnp.arange(a, b_end, dtype=jnp.int32)
+            init = (h, aux_total, cache_kind, prev) if incremental \
+                else (h, aux_total, cache_kind)
+            carry, _ = jax.lax.scan(body, init, layer_ids)
             if incremental:
-                h, aux_total, cache_seg, prev = carry
+                h, aux_total, cache_kind, prev = carry
             else:
-                h, aux_total, cache_seg = carry
-            new_slices.append(cache_seg)
-        new_cache = {kind: jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *new_slices)}
-        return h, new_cache, aux_total
+                h, aux_total, cache_kind = carry
+        return h, {kind: cache_kind}, aux_total
 
     # Unrolled path: exact per-layer k; hybrid / SSM blocks recompute fully.
     per_kind_new: Dict[str, List] = {}

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jax_core
 
 from repro.configs import get_arch, reduced
 from repro.configs.base import SPAConfig
@@ -104,11 +105,7 @@ def test_attn_out_identifier_runs():
     assert not bool(jnp.isnan(h).any())
 
 
-def test_bucketed_scan_matches_unrolled():
-    """8-layer homogeneous model: the bucketed lax.scan serve path must
-    match the exact unrolled path up to bucket over-provisioning (which
-    only ever refreshes MORE rows, so we compare at uniform rho where
-    buckets are exact)."""
+def _setup_8_layers():
     cfg = reduced(get_arch("internlm2-1.8b"), n_layers=8)
     cfg = dataclasses.replace(cfg, spa=SPAConfig(
         identifier="singular", rank=16, schedule="uniform",
@@ -119,8 +116,35 @@ def test_bucketed_scan_matches_unrolled():
     tokens = jax.random.randint(key, (2, 24), 0, cfg.vocab_size - 1)
     _, cache = decoding.prefill(params, cfg, {"tokens": tokens}, proxies)
     h0 = transformer.embed_inputs(params, cfg, {"tokens": tokens})
-    h0 = h0.at[:, 2].add(1.0)
+    return cfg, params, proxies, cache, h0.at[:, 2].add(1.0)
 
+
+def _fixed_segments(monkeypatch, bounds):
+    """Make ``budget.bucketize`` return the segments ``bounds`` gives."""
+    if bounds is not None:
+        monkeypatch.setattr(
+            spa_layer.budget, "bucketize",
+            lambda ks, n_buckets: [(a, b, max(ks[a:b])) for a, b in bounds])
+
+
+SEGMENTATIONS = {
+    "bucketize": None,
+    "one": [(0, 8)],
+    "cell": [(0, 3)] + [(l, l + 1) for l in range(3, 8)],
+    "offset": [(0, 2), (2, 5), (5, 8)],
+}
+
+
+@pytest.mark.parametrize("bounds", list(SEGMENTATIONS.values()),
+                         ids=list(SEGMENTATIONS))
+def test_bucketed_scan_matches_unrolled(monkeypatch, bounds):
+    """8-layer homogeneous model: the bucketed lax.scan serve path must
+    match the exact unrolled path up to bucket over-provisioning (which
+    only ever refreshes MORE rows, so we compare at uniform rho where
+    buckets are exact).  Segments that start past layer 0 check that
+    each scan reads and writes its layers at their absolute index."""
+    _fixed_segments(monkeypatch, bounds)
+    cfg, params, proxies, cache, h0 = _setup_8_layers()
     cfg_scan = dataclasses.replace(cfg, scan_layers=True)
     cfg_unroll = dataclasses.replace(cfg, scan_layers=False)
     h_scan, cache_s, _ = spa_layer.spa_forward(params, cfg_scan, cache,
@@ -133,3 +157,49 @@ def test_bucketed_scan_matches_unrolled():
         np.testing.assert_allclose(
             np.asarray(cache_s["attn"][name]),
             np.asarray(cache_u["attn"][name]), rtol=1e-4, atol=1e-4)
+
+
+def _in(v, stacks):
+    return isinstance(v, jax_core.Var) and v in stacks
+
+
+def _walk(jaxpr, stacks, found):
+    """Record (primitive, operand-is-a-stack, output shapes) of every
+    equation, following ``stacks`` (vars holding a whole weight or proxy
+    stack) into sub-jaxprs whose inputs line up with the equation's."""
+    for eqn in jaxpr.eqns:
+        found.append((eqn.primitive.name,
+                      any(_in(v, stacks) for v in eqn.invars),
+                      [tuple(v.aval.shape) for v in eqn.outvars]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            ins = eqn.invars[len(eqn.invars) - len(sub.invars):]
+            inner = {s for s, v in zip(sub.invars, ins) if _in(v, stacks)}
+            _walk(sub, inner, found)
+
+
+def test_bucketed_scan_copies_no_stack(monkeypatch):
+    """No segment slices a weight or proxy stack (a slice feeding the
+    scan's ``while`` is a copy every step) and no concatenate rebuilds a
+    cache buffer: each scan indexes the full stacks by layer."""
+    _fixed_segments(monkeypatch, SEGMENTATIONS["offset"])
+    cfg, params, proxies, cache, h0 = _setup_8_layers()
+    cfg = dataclasses.replace(cfg, scan_layers=True)
+    closed = jax.make_jaxpr(
+        lambda p, px, c, h: spa_layer.spa_forward(p, cfg, c, h, px))(
+            params, proxies, cache, h0)
+    # make_jaxpr's inputs are the leaves of (params, proxies, cache, h0)
+    # in order; the stacks are params["blocks"] and every proxy leaf.
+    paths = [path for path, _ in
+             jax.tree_util.tree_flatten_with_path((params, proxies))[0]]
+    is_stack = [path[0].idx == 1 or path[1].key == "blocks"
+                for path in paths]
+    stacks = {v for v, st in zip(closed.jaxpr.invars, is_stack) if st}
+    assert len(stacks) == (len(jax.tree.leaves(params["blocks"]))
+                           + len(jax.tree.leaves(proxies)))
+    found = []
+    _walk(closed.jaxpr, stacks, found)
+    assert any(prim == "scan" for prim, _, _ in found)
+    assert not [f for f in found if f[0] == "slice" and f[1]]
+    cache_shapes = {tuple(x.shape) for x in jax.tree.leaves(cache)}
+    assert not [f for f in found if f[0] == "concatenate"
+                and cache_shapes & set(f[2])]
