@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib.util
 import json
 import math
 import os
@@ -35,10 +36,6 @@ from traffic import Request
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
-# configuration-file keys handed to the program's ModelConfig
-MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab_size", "tie_embeddings", "act", "rope_theta",
-              "norm_eps", "param_dtype", "cache_dtype")
 WARM_GEN = 8           # generation length of the warm-up requests
 
 
@@ -64,13 +61,26 @@ def mask_id(cfg: Dict[str, Any]) -> int:
     return cfg.get("mask_token_id") or cfg["vocab_size"] - 1
 
 
-def model_config(cfg: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs import get_arch
-    from repro.configs.base import SPAConfig
-    return dataclasses.replace(
-        get_arch(cfg["arch"]), name=cfg["name"], mask_token_id=mask_id(cfg),
-        spa=SPAConfig(**cfg["spa"]), **{k: cfg[k] for k in MODEL_KEYS})
+def load_module(path: str, name: str):
+    """The Python file at ``path``, loaded as a module of its own."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(cfg: Dict[str, Any], bench_dir: str = HERE):
+    """The model family a configuration file names: the module
+    ``families/<family>.py`` under ``bench_dir``, which holds all the
+    harness knows of the architecture (``families/dense.py`` says
+    what).  A file that names none, or one not there, is an error."""
+    fdir = os.path.join(bench_dir, "families")
+    known = sorted(f[:-3] for f in os.listdir(fdir) if f.endswith(".py"))
+    name = cfg.get("family")
+    if name not in known:
+        raise SystemExit(f"configuration {cfg.get('name')!r} names model "
+                         f"family {name!r}; known families: {known}")
+    return load_module(os.path.join(fdir, f"{name}.py"), f"family_{name}")
 
 
 def build_engine(mcfg, params, mix: Dict[str, Any]):
@@ -271,13 +281,17 @@ class Window:
         self.step_times.append(now)
         self._trace_tick(now)
         if self.mix["loop"] == "closed" and self.seconds is not None:
-            if now >= self.t0 + self.seconds:
+            if self._closes(now):
                 self._t_end = now
                 self._tokens_end = engine.stats.tokens_committed
                 self._finish_trace()
                 self.stop.set()
                 return
             self._top_up()
+
+    def _closes(self, now: float) -> bool:
+        """Whether a closed-loop window closes at this step boundary."""
+        return now >= self.t0 + self.seconds
 
     def _finish_trace(self) -> None:
         if self._iter_span is not None:

@@ -106,24 +106,26 @@ def replay(recs: List[Record], steps: List[List[int]],
     return canvases, kv, pos, toks, valid
 
 
-def gap_readings(cfg: Dict[str, Any], params, recs: List[Record],
+def gap_readings(family, cfg: Dict[str, Any], params, recs: List[Record],
                  mix: Dict[str, Any], steps: List[List[int]],
                  control: bool = False) -> np.ndarray:
-    """The gaps of every token the replayed steps committed.  With
-    ``control`` they are the gaps of the token the int8 control puts
-    first at the same positions."""
+    """The gaps of every token the replayed steps committed, against the
+    reference of the configuration's ``family``.  With ``control`` they
+    are the gaps of the token the int8 control puts first at the same
+    positions."""
     mask_id = cell.mask_id(cfg)
     canvases, kv, pos, toks, valid = replay(recs, steps, mix, mask_id)
     if not len(canvases):
         return np.zeros(0)
     flat_toks = toks.reshape(-1)
     if control:
-        low = reference.hidden_at(cfg, params, canvases, kv, pos, int8=True)
+        low = reference.hidden_at(family, cfg, params, canvases, kv, pos,
+                                  int8=True)
         _, flat_toks = reference.gaps_at(
             cfg, params, low.reshape(-1, low.shape[-1]), flat_toks,
             int8=True)
         del low
-    rows = reference.hidden_at(cfg, params, canvases, kv, pos)
+    rows = reference.hidden_at(family, cfg, params, canvases, kv, pos)
     gaps, _ = reference.gaps_at(cfg, params, rows.reshape(-1, rows.shape[-1]),
                                 flat_toks)
     return gaps[valid.reshape(-1)]
@@ -137,7 +139,7 @@ def gap_numbers(gaps: np.ndarray) -> Dict[str, float]:
     return out
 
 
-def numbers(cfg: Dict[str, Any], params, win: WindowResult,
+def numbers(family, cfg: Dict[str, Any], params, win: WindowResult,
             mix: Dict[str, Any], limits: Dict[str, Any], seed: int
             ) -> Dict[str, float]:
     """Every number the cell's limits are set on."""
@@ -150,7 +152,8 @@ def numbers(cfg: Dict[str, Any], params, win: WindowResult,
         "stalled_requests": float(stalled(win)),
     }
     steps = sample_steps(done, limits["canvases"], seed)
-    out.update(gap_numbers(gap_readings(cfg, params, done, mix, steps)))
+    out.update(gap_numbers(gap_readings(family, cfg, params, done, mix,
+                                        steps)))
     return out
 
 

@@ -3,12 +3,13 @@
 A reader is ``metrics/<metric>.py`` with ``read(ctx) -> float | None``;
 ``None`` means it found nothing to read in this run, and the metric is
 left out of the result line.  End-to-end readers use the window's host
-clock; per-layer readers the trace (``ctx.trace``) and the costs.
+clock; per-layer readers the trace (``ctx.trace``), the costs of the
+shared kernels (``costs``) and the configuration's model family
+(``ctx.family``) for the work of the architecture.
 """
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import os
 from typing import Any, Dict, List, Optional
 
@@ -16,7 +17,7 @@ import numpy as np
 
 import costs
 import xplane
-from cell import WindowResult, row_len
+from cell import WindowResult, load_module, row_len
 
 # the jitted serve step, by the name of the program's module for it
 STEP_MODULE = r"serve_step"
@@ -31,6 +32,7 @@ class Ctx:
     setup_s: float
     device_kind: str
     trace: Optional[xplane.Trace] = None
+    family: Any = None               # the configuration's (cell.family)
 
     # ---- host clock ------------------------------------------------------
 
@@ -76,9 +78,6 @@ def percentile(values, q: float) -> Optional[float]:
 
 
 def reader(metrics_dir: str, name: str):
-    path = os.path.join(metrics_dir, f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(os.path.join(metrics_dir, f"{name}.py"),
+                       "metric_" + name.replace(".", "_").replace("-", "_")
+                       ).read

@@ -30,7 +30,6 @@ import run  # noqa: E402  (puts the program on the path)
 import cell  # noqa: E402
 import check  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 
 
 def benchmark(root: str = run.ROOT, bench_dir: str = HERE):
@@ -47,10 +46,11 @@ def readings(workload: str, seed: int, seconds: float, root: str = run.ROOT,
     import jax
     cel, entry = cell.find_cell(benchmark(root, bench_dir), workload)
     cfg = cell.load_json(root, entry["file"])
+    fam = cell.family(cfg, bench_dir)
     mix = traffic.load_mix(cel["traffic"], bench_dir)
     limits = check.load_limits(workload, bench_dir)
-    params = jax.block_until_ready(weights.make_params(cfg, seed))
-    engine = cell.build_engine(cell.model_config(cfg), params, mix)
+    params = jax.block_until_ready(fam.make_params(cfg, seed))
+    engine = cell.build_engine(fam.model_config(cfg), params, mix)
     mask = cell.mask_id(cfg)
     phases = [(lane, None)
               for lane in cell.warm_lanes(mix, cfg["vocab_size"], mask)]
@@ -60,13 +60,14 @@ def readings(workload: str, seed: int, seconds: float, root: str = run.ROOT,
         win = cell.Window(engine, mix, requests, secs).run()
     del engine, params
     gc.collect()
-    params = weights.make_params(cfg, seed)
+    params = fam.make_params(cfg, seed)
     done = [r for r in win.records if r.output is not None]
     steps = check.sample_steps(done, limits["canvases"], seed)
     out = {"workload": workload, "seed": seed, "finished": len(done)}
     for name, ctl in (("program", False), ("control", True)):
         nums = check.gap_numbers(
-            check.gap_readings(cfg, params, done, mix, steps, control=ctl))
+            check.gap_readings(fam, cfg, params, done, mix, steps,
+                               control=ctl))
         nums["finished"] = float(len(done))
         nums["bookkeeping_faults"] = nums["stalled_requests"] = 0.0
         if not ctl:

@@ -1,4 +1,6 @@
-"""Peaks of the chip, and the work a step requires, from shapes alone.
+"""Peaks of the chip, and the work of the shared kernels, from shapes
+alone.  The work of a whole serve step depends on the architecture and
+is its family's (``families/<family>.py``, ``step_flops``).
 
 Every count here is of REQUIRED work: the exact per-layer update count
 ``k(l) = ceil(rho(l) * canvas)`` of the paper's Eq. (5) (not the
@@ -54,16 +56,17 @@ def k_exact(cfg: Dict[str, Any], canvas: int) -> List[int]:
             for r in rho_schedule(cfg["spa"], cfg["n_layers"])]
 
 
-def _dims(cfg):
-    d, hd = cfg["d_model"], cfg["head_dim"]
-    return d, cfg["n_heads"] * hd, cfg["n_kv_heads"] * hd, cfg["d_ff"]
+def attention_dims(cfg: Dict[str, Any]) -> Tuple[int, int]:
+    """(query width, key/value width) of one layer's attention."""
+    hd = cfg["head_dim"]
+    return cfg["n_heads"] * hd, cfg["n_kv_heads"] * hd
 
 
 def sparse_attention(cfg: Dict[str, Any], k: int, kv_len: int
                      ) -> Tuple[float, float]:
     """(FLOPs, bytes) of one row's sparse attention in one layer: ``k``
     selected queries against ``kv_len`` cached keys and values."""
-    _, q, kv, _ = _dims(cfg)
+    q, kv = attention_dims(cfg)
     k = min(k, kv_len)
     flops = 4.0 * k * q * kv_len
     nbytes = BF16 * (2 * k * q + 2 * kv_len * kv)
@@ -101,20 +104,6 @@ def mean_candidates(mix: Dict[str, Any]) -> float:
         blk = sched["block_len"]
         return sum(blk - done % blk for done in range(g)) / g
     return sum(min(CANDIDATE_SET, g - done) for done in range(g)) / g
-
-
-def step_flops(cfg: Dict[str, Any], canvas: int, kv_len: int,
-               candidates: float) -> float:
-    """FLOPs one live row's serve step requires."""
-    d, q, kv, ff = _dims(cfg)
-    r = cfg["spa"]["rank"]
-    dense = 2.0 * (d * (q + 2 * kv) + q * d + 3 * d * ff)
-    total = 0.0
-    for k in k_exact(cfg, canvas):
-        k = min(k, kv_len)
-        total += k * dense + sparse_attention(cfg, k, kv_len)[0]
-        total += 2.0 * kv_len * d * r
-    return total + 2.0 * candidates * d * cfg["vocab_size"]
 
 
 def roofline_time(flops: float, nbytes: float, pk: Dict[str, float]

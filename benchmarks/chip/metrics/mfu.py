@@ -1,8 +1,7 @@
 """FLOPs the traced steps require over the traced window at the chip's
-bf16 peak: per live row and layer, the exact k(l) rows through QKV, O
-and the MLP, their attention over kv_len keys, the rank-r projection of
-every valid row, and the logits of the candidates the scheduler may
-commit from."""
+bf16 peak: per live row, the work its configuration's family counts for
+one serve step (``step_flops``), with the logits of the candidates the
+scheduler may commit from."""
 import costs
 
 
@@ -12,6 +11,6 @@ def read(ctx):
     if kv_len is None or not rows or not steps:
         return None
     cand = costs.mean_candidates(ctx.mix)
-    need = steps * rows * costs.step_flops(ctx.cfg, ctx.mix["canvas"],
-                                           kv_len, cand)
+    need = steps * rows * ctx.family.step_flops(
+        ctx.cfg, ctx.mix["canvas"], kv_len, cand)
     return 100.0 * need / (ctx.trace.window_s * ctx.peaks()["bf16_flops"])

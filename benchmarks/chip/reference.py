@@ -1,22 +1,23 @@
 """Plain float32 reference of the served model, and its int8 control.
 
-A straightforward forward pass of the configuration's architecture —
-token embedding, RMSNorm (weights applied as ``1 + w``), rotary
-positions (half-split), bidirectional grouped-query attention over the
-first ``kv_len`` positions of each canvas, gated SiLU feed-forward, and
-the LM head — in float32 at ``Precision.HIGHEST``, with no cache, no
-kernels and nothing of the serving program.  It is computed layer by
-layer over blocks of canvases, so it fits beside nothing else on the
-chip.
+A straightforward forward pass — token embedding, the layers, the final
+RMSNorm (weights applied as ``1 + w``) and the LM head — in float32 at
+``Precision.HIGHEST``, with no cache, no kernels and nothing of the
+serving program.  What a layer computes is its family's
+(``families/<family>.py``, ``reference_layer``); this module embeds,
+blocks the canvases, hands each layer its weights and reads the
+logits.  It is computed layer by layer over blocks of canvases, so it
+fits beside nothing else on the chip.
 
 ``hidden_at`` runs whole canvases and keeps the final hidden rows at
 the positions asked for; ``gaps_at`` turns them into logits and reads
 how far a given token lies below the best.  ``int8=True`` is the
 control, the step below the bf16 the configuration serves: every
-matrix product of the projections, the feed-forward and the LM head
-computed in int8 — weights quantized per output channel, inputs per
-row (symmetric, round to nearest), as an int8 matrix unit takes them —
-with attention and the norms left in float32.
+matrix product with a weight matrix — the layers' and the LM head's —
+computed in int8, weights quantized per output channel (every leaf of
+rank 2 or more along its input axis, -2), inputs per row (symmetric,
+round to nearest), as an int8 matrix unit takes them, with attention
+and the norms left in float32.
 """
 from __future__ import annotations
 
@@ -30,16 +31,16 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _mm(a, b):
+def mm(a, b):
     return jnp.matmul(a, b, precision=HIGHEST)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return x * (1.0 + w)
 
 
-def _rope(x, positions, theta):
+def rope(x, positions, theta):
     hd = x.shape[-1]
     half = hd // 2
     freqs = 1.0 / (theta ** (np.arange(half, dtype=np.float32) * 2 / hd))
@@ -59,53 +60,27 @@ def quantize_int8(w: jax.Array, in_axis: int) -> jax.Array:
     return jnp.clip(jnp.round(w / scale), -127, 127) * scale
 
 
-def _mm_in(x, w, int8: bool):
+def mm_in(x, w, int8: bool):
     """A matrix product; with ``int8`` its input rows are quantized too
     (the weights already are)."""
-    return _mm(quantize_int8(x, -1) if int8 else x, w)
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _layer(shape_cfg, h, w, kv_len):
-    n_heads, n_kv, hd, theta, eps, int8 = shape_cfg
-    b, n, _ = h.shape
-    pos = jnp.broadcast_to(jnp.arange(n)[None], (b, n))
-    x = _rms(h, w["norm1"], eps)
-    q = _rope(_mm_in(x, w["wq"], int8).reshape(b, n, n_heads, hd), pos,
-              theta)
-    k = _rope(_mm_in(x, w["wk"], int8).reshape(b, n, n_kv, hd), pos, theta)
-    v = _mm_in(x, w["wv"], int8).reshape(b, n, n_kv, hd)
-    g = n_heads // n_kv
-    qg = q.reshape(b, n, n_kv, g, hd)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, precision=HIGHEST) / hd ** 0.5
-    valid = jnp.arange(n)[None, :] < kv_len[:, None]
-    s = jnp.where(valid[:, None, None, None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v, precision=HIGHEST)
-    h = h + _mm_in(o.reshape(b, n, n_heads * hd), w["wo"], int8)
-    y = _rms(h, w["norm2"], eps)
-    f = w["ffn"]
-    act = (jax.nn.silu(_mm_in(y, f["w_gate"], int8))
-           * _mm_in(y, f["w_up"], int8))
-    return h + _mm_in(act, f["w_down"], int8)
+    return mm(quantize_int8(x, -1) if int8 else x, w)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _gaps(mask_id, int8, h_rows, table_t, toks):
-    logits = _mm_in(h_rows, table_t, int8).at[:, mask_id].set(-jnp.inf)
+    logits = mm_in(h_rows, table_t, int8).at[:, mask_id].set(-jnp.inf)
     best = jnp.max(logits, axis=-1)
     mine = jnp.take_along_axis(logits, toks[:, None], axis=-1)[:, 0]
     return best - mine, jnp.argmax(logits, axis=-1)
 
 
 def _layer_weights(params, l: int, int8: bool):
-    w = jax.tree.map(lambda t: t[l].astype(jnp.float32),
-                     params["blocks"]["attn"])
-    if int8:
-        for name in ("wq", "wk", "wv", "wo"):
-            w[name] = quantize_int8(w[name], 0)
-        w["ffn"] = {k: quantize_int8(v, 0) for k, v in w["ffn"].items()}
-    return w
+    """Layer ``l``'s weights in float32; with ``int8`` every matrix
+    quantized along its input axis."""
+    def one(t):
+        t = t[l].astype(jnp.float32)
+        return quantize_int8(t, -2) if int8 and t.ndim >= 2 else t
+    return jax.tree.map(one, params["blocks"]["attn"])
 
 
 def _embed(params, int8: bool):
@@ -113,12 +88,13 @@ def _embed(params, int8: bool):
     return quantize_int8(embed, 1) if int8 else embed
 
 
-def hidden_at(cfg: Dict[str, Any], params, tokens: np.ndarray,
+def hidden_at(family, cfg: Dict[str, Any], params, tokens: np.ndarray,
               kv_len: np.ndarray, pos: np.ndarray, *, int8: bool = False,
               block: int = 8) -> jax.Array:
     """Final-normed hidden rows [C, M, d] at positions ``pos`` [C, M] of
     canvases ``tokens`` [C, N], each valid for its first ``kv_len[c]``
-    positions.  Canvases run ``block`` at a time, layer by layer."""
+    positions.  Canvases run ``block`` at a time, layer by layer, each
+    layer as ``family.reference_layer`` computes it."""
     c = tokens.shape[0]
     pad = (-c) % block
     tokens = np.concatenate([tokens, np.repeat(tokens[:1], pad, 0)])
@@ -129,16 +105,15 @@ def hidden_at(cfg: Dict[str, Any], params, tokens: np.ndarray,
     del embed
     kvs = [jnp.asarray(kv_len[i:i + block], jnp.int32)
            for i in range(0, len(tokens), block)]
-    shape_cfg = (cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"],
-                 float(cfg["rope_theta"]), float(cfg["norm_eps"]), int8)
     for l in range(cfg["n_layers"]):
         w = _layer_weights(params, l, int8)
-        hs = [_layer(shape_cfg, h, w, kv) for h, kv in zip(hs, kvs)]
+        hs = [family.reference_layer(cfg, l, h, w, kv, int8)
+              for h, kv in zip(hs, kvs)]
         del w
     rows = jnp.concatenate(hs)[:c]
     del hs
     rows = jnp.take_along_axis(rows, jnp.asarray(pos)[..., None], axis=1)
-    return _rms(rows, params["final_norm"].astype(jnp.float32),
+    return rms(rows, params["final_norm"].astype(jnp.float32),
                 float(cfg["norm_eps"]))
 
 

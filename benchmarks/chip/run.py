@@ -7,8 +7,9 @@
 From the root of a checkout, on a machine whose JAX sees the TPU chips
 the cell asks for (it exits 3, printing no result, on any other
 device).  Everything is found by name: the cell in ``BENCHMARK.json``,
-its configuration file, ``traffic/<mix>.json``, ``checks/<cell>.json``
-and one reader per metric in ``metrics/``.
+its configuration file, the model family it names
+(``families/<family>.py``), ``traffic/<mix>.json``,
+``checks/<cell>.json`` and one reader per metric in ``metrics/``.
 
 One run: seeded bf16 weights made on the chip, the serving engine, a
 warm-up of every shape the mix produces (set-up ends here), the window
@@ -43,7 +44,6 @@ import check  # noqa: E402
 import context  # noqa: E402
 import xplane  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 
 TRACE_AT, TRACE_LEN = 0.4, 0.15     # share of the window: start, length
 TRACE_MAX_S = 6.0
@@ -102,6 +102,7 @@ def execute(args, root: str = ROOT, bench_dir: str = HERE, devices=None,
     bench = cell.load_json(root, "BENCHMARK.json")
     cel, entry = cell.find_cell(bench, args.workload)
     cfg = cell.load_json(root, entry["file"])
+    fam = cell.family(cfg, bench_dir)
     mix = traffic.load_mix(cel["traffic"], bench_dir)
     limits = check.load_limits(args.workload, bench_dir)
     devs = devices if devices is not None else tpu_devices(cel["chips"])
@@ -110,8 +111,8 @@ def execute(args, root: str = ROOT, bench_dir: str = HERE, devices=None,
           file=sys.stderr, flush=True)
 
     import jax
-    mcfg = cell.model_config(cfg)
-    params = jax.block_until_ready(weights.make_params(cfg, args.seed))
+    mcfg = fam.model_config(cfg)
+    params = jax.block_until_ready(fam.make_params(cfg, args.seed))
     engine = cell.build_engine(mcfg, params, mix)
     from jax import monitoring
     if _on_event not in getattr(execute, "_listening", ()):
@@ -167,11 +168,12 @@ def execute(args, root: str = ROOT, bench_dir: str = HERE, devices=None,
 
     del engine, params
     gc.collect()
-    nums = check.numbers(cfg, weights.make_params(cfg, args.seed), win, mix,
-                         limits, args.seed)
+    nums = check.numbers(fam, cfg, fam.make_params(cfg, args.seed), win,
+                         mix, limits, args.seed)
     correct, rows = check.judge(nums, limits)
 
-    ctx = context.Ctx(cel, cfg, mix, win, setup_s, dev.device_kind)
+    ctx = context.Ctx(cel, cfg, mix, win, setup_s, dev.device_kind,
+                      family=fam)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs), "memory_peak_bytes": int(peak)}
     result = {"correct": bool(correct),

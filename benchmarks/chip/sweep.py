@@ -28,7 +28,6 @@ import run  # noqa: E402  (puts the program on the path)
 import cell  # noqa: E402
 import control  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -43,9 +42,10 @@ def main(argv=None) -> int:
     import jax
     cel, entry = cell.find_cell(control.benchmark(), args.workload)
     cfg = cell.load_json(run.ROOT, entry["file"])
+    fam = cell.family(cfg)
     mix = traffic.load_mix(cel["traffic"])
-    params = jax.block_until_ready(weights.make_params(cfg, args.seed))
-    engine = cell.build_engine(cell.model_config(cfg), params, mix)
+    params = jax.block_until_ready(fam.make_params(cfg, args.seed))
+    engine = cell.build_engine(fam.model_config(cfg), params, mix)
     rates = [float(r) for r in args.rates.split(",")]
     # warm-up lanes, then one window per rate, from one call site
     phases = [(mix, lane, None) for lane in

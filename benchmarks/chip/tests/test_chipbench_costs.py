@@ -14,6 +14,7 @@ ROOT = os.path.dirname(os.path.dirname(CHIP))
 sys.path.insert(0, CHIP)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
+import cell  # noqa: E402
 import costs  # noqa: E402
 
 
@@ -55,12 +56,13 @@ def test_proxy_score_counts():
 
 def test_step_flops_parts():
     cfg = _cfg("llada-8b-l8")
+    fam = cell.family(cfg)
     d, ff, v = 4096, 12288, 126464
     dense = 2 * (d * 3 * d + d * d + 3 * d * ff)
     ks = costs.k_exact(cfg, 768)
     want = sum(k * dense + 4 * k * d * 768 + 2 * 768 * d * 128 for k in ks)
     want += 2 * 56.0 * d * v
-    assert costs.step_flops(cfg, 768, 768, 56.0) == pytest.approx(want)
+    assert fam.step_flops(cfg, 768, 768, 56.0) == pytest.approx(want)
 
 
 def test_mean_candidates():

@@ -1,5 +1,7 @@
 """Each cell's runner driven in-process on the CPU at a tiny size: the
-whole run but the look for a chip, on the cell's own path."""
+whole run but the look for a chip, on the cell's own path.  The window
+closes once ``tiny.FINISHED`` requests have finished, not after wall
+seconds, so a loaded CPU only makes it longer."""
 from __future__ import annotations
 
 import os
@@ -22,8 +24,9 @@ CELLS = [w["name"] for w in tiny.benchmark()["workloads"]
 SEED = 2 ** 31 + 7
 
 
-def tiny_run(tmp_path, workload, seconds=4.0):
+def tiny_run(tmp_path, monkeypatch, workload, seconds=4.0):
     import jax
+    tiny.close_by_finished(monkeypatch)
     root = tiny.make_root(str(tmp_path / "root"))
     args = run.parse(["--workload", workload, "--seed", str(SEED),
                       "--seconds", str(seconds), "--trace", "0"])
@@ -34,9 +37,10 @@ def tiny_run(tmp_path, workload, seconds=4.0):
 
 
 @pytest.mark.parametrize("workload", CELLS)
-def test_cell_runs_correct(tmp_path, workload):
-    res = tiny_run(tmp_path, workload)
+def test_cell_runs_correct(tmp_path, monkeypatch, workload):
+    res = tiny_run(tmp_path, monkeypatch, workload)
     assert res["correct"] is True, res["checks"]
+    assert res["checks"]["finished"]["value"] >= tiny.FINISHED
     assert res["failed"] == 0 and res["attempted"] > 0
     want = {m["name"] for m in tiny.benchmark()["end_to_end"]
             if workload in m.get("workloads", [workload])}

@@ -13,6 +13,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CHIP = os.path.dirname(HERE)
 sys.path.insert(0, CHIP)
 
+import cell  # noqa: E402
 import context  # noqa: E402
 import costs  # noqa: E402
 import xplane  # noqa: E402
@@ -33,7 +34,7 @@ def _ctx(tr, workload="llada-8b-l8.blockwise-offline"):
         mix = json.load(f)
     win = WindowResult(0.0, 1.0, 0, [], [], {})
     return context.Ctx({"name": workload}, cfg, mix, win, 1.0,
-                       "TPU v5 lite", trace=tr)
+                       "TPU v5 lite", trace=tr, family=cell.family(cfg))
 
 
 def _read(name, ctx):
@@ -77,7 +78,7 @@ def test_work_readers_follow_the_costs(tr):
     one = costs.roofline_time(*costs.proxy_score(cfg, kv, rows), pk)[0]
     assert _read("proxy_score_roofline", ctx) == pytest.approx(
         100 * 2 * 8 * one / 0.002)
-    flops = 2 * rows * costs.step_flops(
+    flops = 2 * rows * ctx.family.step_flops(
         cfg, 768, kv, costs.mean_candidates(mix))
     assert _read("mfu", ctx) == pytest.approx(
         100 * flops / (0.033 * pk["bf16_flops"]))

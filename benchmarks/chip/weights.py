@@ -1,19 +1,23 @@
-"""Seeded model weights, made on the device in one jitted call.
+"""Helpers every family's weight draw shares.
 
-The tree has the layout the serving program takes (``embed``,
-``final_norm``, optional ``lm_head``, and ``blocks.attn`` stacks with a
-leading layer axis), in the dtype the configuration serves
-(``param_dtype``).  Each layer draws from its own key,
-``fold_in(blocks_key, layer)``, so the same seed always gives the same
-weights, whichever program draws them.
+A family (``families/<family>.py``) draws the whole tree its
+configurations serve, on the device, from the run seed's key, in the
+dtype the configuration serves (``param_dtype``).  The tree has the
+layout the serving program takes: ``embed``, ``final_norm``, optional
+``lm_head``, and the ``blocks.attn`` stack with a leading layer axis.
+So the same seed always gives the same weights, whichever program
+draws them.
 
 Scales: projections N(0, 1/fan_in), embedding N(0, 0.02^2), norm
 weights N(0, 0.1^2) (applied as ``1 + w``).
+
+``stacked_params`` draws a tree whose layers all fit one jitted call;
+a family too large for that draws its own, layer by layer.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Callable, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -31,31 +35,12 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.wrap_key_data(jnp.asarray(words, jnp.uint32))
 
 
-def _normal(key, shape, std, dtype):
+def normal(key, shape, std, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def _layer(cfg: Dict[str, Any], dtype, key) -> Dict[str, Any]:
-    d, q = cfg["d_model"], cfg["n_heads"] * cfg["head_dim"]
-    kv, ff = cfg["n_kv_heads"] * cfg["head_dim"], cfg["d_ff"]
-    k = jax.random.split(key, 9)
-    return {
-        "norm1": _normal(k[0], (d,), NORM_STD, dtype),
-        "wq": _normal(k[1], (d, q), d ** -0.5, dtype),
-        "wk": _normal(k[2], (d, kv), d ** -0.5, dtype),
-        "wv": _normal(k[3], (d, kv), d ** -0.5, dtype),
-        "wo": _normal(k[4], (q, d), q ** -0.5, dtype),
-        "norm2": _normal(k[5], (d,), NORM_STD, dtype),
-        "ffn": {
-            "w_gate": _normal(k[6], (d, ff), d ** -0.5, dtype),
-            "w_up": _normal(k[7], (d, ff), d ** -0.5, dtype),
-            "w_down": _normal(k[8], (ff, d), ff ** -0.5, dtype),
-        },
-    }
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _make(frozen_cfg, key):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _stacked(layer, frozen_cfg, key):
     cfg = dict(frozen_cfg)
     dtype = jnp.dtype(cfg["param_dtype"])
     d, v = cfg["d_model"], cfg["vocab_size"]
@@ -63,21 +48,21 @@ def _make(frozen_cfg, key):
     layer_keys = jax.vmap(lambda l: jax.random.fold_in(k_blocks, l))(
         jnp.arange(cfg["n_layers"]))
     params = {
-        "embed": _normal(k_embed, (v, d), EMBED_STD, dtype),
-        "final_norm": _normal(k_norm, (d,), NORM_STD, dtype),
+        "embed": normal(k_embed, (v, d), EMBED_STD, dtype),
+        "final_norm": normal(k_norm, (d,), NORM_STD, dtype),
         "blocks": {"attn": jax.vmap(
-            functools.partial(_layer, cfg, dtype))(layer_keys)},
+            functools.partial(layer, cfg, dtype))(layer_keys)},
     }
     if not cfg["tie_embeddings"]:
-        params["lm_head"] = _normal(k_head, (d, v), d ** -0.5, dtype)
+        params["lm_head"] = normal(k_head, (d, v), d ** -0.5, dtype)
     return params
 
 
-WEIGHT_KEYS = ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-               "vocab_size", "n_layers", "tie_embeddings", "param_dtype")
-
-
-def make_params(cfg: Dict[str, Any], seed: int) -> Dict[str, Any]:
-    """All weights of ``cfg`` (a configuration file's dict) for ``seed``."""
-    frozen = tuple(sorted((k, cfg[k]) for k in WEIGHT_KEYS))
-    return _make(frozen, seed_key(seed))
+def stacked_params(layer: Callable, cfg: Dict[str, Any],
+                   keys: Sequence[str], seed: int) -> Dict[str, Any]:
+    """All weights of ``cfg`` for ``seed``, in one jitted call: each
+    layer drawn by ``layer(cfg, dtype, key)`` from its own key,
+    ``fold_in(blocks_key, layer)``.  ``keys`` are the configuration keys
+    the draw reads."""
+    frozen = tuple(sorted((k, cfg[k]) for k in keys))
+    return _stacked(layer, frozen, seed_key(seed))
